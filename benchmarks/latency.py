@@ -68,7 +68,7 @@ def run() -> list:
     # NOTE: the Pallas kernel runs in interpret mode on CPU (orders of
     # magnitude slower than compiled TPU execution); we report its VALIDATED
     # numerical match instead of a misleading CPU wall time.
-    y1 = np.asarray(sbmm(x, pk, tm=64))
+    y1 = np.asarray(sbmm(x, pk))
     y2 = np.asarray(x @ dense_w)
     rows.append(("sbmm.kernel_max_abs_err", float(np.abs(y1 - y2).max()),
                  "interpret-mode validation"))

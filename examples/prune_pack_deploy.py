@@ -44,7 +44,7 @@ def main():
         loads = packing.lane_loads(mk.sum(0).astype(np.int64), pk.col_perm, 8)
         if checked < 2:  # validate a couple of kernels end to end
             x = jax.random.normal(key, (16, w.shape[0]))
-            err = float(jnp.abs(sbmm(x, pk, tm=16) - x @ pk.to_dense()).max())
+            err = float(jnp.abs(sbmm(x, pk) - x @ pk.to_dense()).max())
             print(f"  {path}: kept {int(mk.sum())}/{mk.size} blocks, "
                   f"lane loads {loads.tolist()}, sbmm err {err:.1e}")
             assert err < 1e-3

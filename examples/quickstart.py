@@ -57,7 +57,7 @@ def main():
 
     # --- SBMM kernel vs masked-dense oracle ------------------------------
     x = jax.random.normal(key, (32, w.shape[0]), jnp.float32)
-    y_kernel = sbmm(x, pk, tm=32)
+    y_kernel = sbmm(x, pk)
     y_oracle = x @ pk.to_dense()
     err = float(jnp.abs(y_kernel - y_oracle).max())
     print(f"SBMM kernel vs oracle: max |err| = {err:.2e}")

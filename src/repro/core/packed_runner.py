@@ -172,7 +172,7 @@ def _proj(params: Dict, packed: Dict, i: int, name: str, inp: jax.Array
           ) -> jax.Array:
     key = f"layers/{i}/attn/{name}"
     if key in packed:
-        return sbmm(inp, packed[key], tm=64)
+        return sbmm(inp, packed[key])
     return L.linear(inp, params["layers"][i]["attn"][name])
 
 
@@ -618,11 +618,6 @@ class PackedVitSegments:
     def jit_compile_count(self) -> int:
         """Total entries across the jit caches (what XLA actually
         compiled), fused trajectory programs included."""
-        total = 0
-        for fn in (self._embed, self._layers, self._tdm, self._tdm_soft,
-                   self._head, self._fused):
-            try:
-                total += fn._cache_size()
-            except AttributeError:  # older jax: fall back to the ledger
-                return self.compile_count
-        return total
+        return sum(fn._cache_size()
+                   for fn in (self._embed, self._layers, self._tdm,
+                              self._tdm_soft, self._head, self._fused))

@@ -8,7 +8,7 @@ the block-compressed format (``core.packing.PackedWeight``) with symmetric
 int8 quantization: the int8 blocks keep the exact ``blocks``/``header``
 layout the SBMM kernel streams, and per-block (or per-output-channel)
 float scales ride alongside as one extra pytree child the dequant-in-kernel
-variant (``kernels.sbmm.sbmm_quant``) prefetches next to the header.
+variant (``kernels.sbmm.sbmm_quant``) streams next to the blocks.
 
 Precisions (the ``precision`` axis the serving stack threads through):
 
@@ -16,6 +16,8 @@ Precisions (the ``precision`` axis the serving stack threads through):
 * ``fp16``  — weights stored as float16 (the fast path: the existing SBMM
   kernel already accumulates in fp32 via ``preferred_element_type``, so
   fp16 blocks ride it unchanged); attention runs on fp16-cast q/k/v.
+  Interpret mode only: compiled Pallas cannot load float16 blocks on a
+  TPU v5e, so the engine refuses this tier where the kernels compile.
 * ``int8``  — symmetric per-block/per-channel int8 blocks + f32 scales,
   dequantized inside the kernel.
 

@@ -8,9 +8,10 @@ The kernels run in two modes:
 Every kernel entry point takes ``interpret: bool | None = None`` and
 resolves ``None`` through :func:`default_interpret`: compiled on a real TPU
 backend, interpreted elsewhere. The ``REPRO_KERNEL_INTERPRET`` environment
-variable overrides auto-detection in either direction (``1``/``true``/
-``interpret`` forces the interpreter, ``0``/``false``/``compiled`` forces
-compiled Pallas, ``auto``/unset keeps detection).
+variable overrides auto-detection (``1``/``true``/``interpret`` forces the
+interpreter, ``0``/``false``/``compiled`` forces compiled Pallas,
+``auto``/unset keeps detection). Forcing the interpreter on a TPU backend
+is an error, not a mode: served kernels there always compile.
 
 Resolution scope: the top-level kernel entry points (``sbmm``,
 ``token_drop``, ``flash_attention``) resolve OUTSIDE their jits, so for
@@ -38,9 +39,14 @@ def on_tpu() -> bool:
 
 
 def default_interpret() -> bool:
-    """Interpret on non-TPU backends unless the env var says otherwise."""
+    """Interpret on non-TPU backends unless the env var says otherwise.
+    Raises if the env var forces the interpreter on a TPU backend."""
     env = os.environ.get(ENV_VAR, "auto").strip().lower()
     if env in _TRUE:
+        if on_tpu():
+            raise RuntimeError(
+                f"{ENV_VAR}={env!r} would run the Pallas kernels in the "
+                f"interpreter on a TPU backend; unset it to compile them")
         return True
     if env in _FALSE:
         return False

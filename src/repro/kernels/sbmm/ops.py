@@ -67,8 +67,9 @@ def sbmm(x: jax.Array, packed: "PackedWeight | QuantizedPackedWeight",
     """Full SBMM: y = x @ W_masked, undoing the load-balancing column
     permutation so callers see logical column order. A
     :class:`QuantizedPackedWeight` dispatches the dequant-in-kernel
-    variant (int8 blocks, scales prefetched); an fp16-blocks PackedWeight
-    rides the standard kernel (fp32 accumulation either way).
+    variant (int8 blocks, scales streamed with them); an fp16-blocks
+    PackedWeight rides the standard kernel in interpret mode only (fp32
+    accumulation either way).
 
     x: [..., M1_any, K]; returns [..., M1_any, M2]."""
     lead = x.shape[:-1]

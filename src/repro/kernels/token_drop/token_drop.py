@@ -36,15 +36,13 @@ def _token_drop_kernel(keep_idx_ref, z_ref, w_ref, out_ref, *, k: int):
     def gather_row(r, _):
         idx = keep_idx_ref[r]
         row = z_ref[pl.dslice(idx, 1), :]
-        pl.store(out_ref, (pl.dslice(r, 1), slice(None)),
-                 row.astype(out_ref.dtype))
+        out_ref[pl.dslice(r, 1), :] = row.astype(out_ref.dtype)
         return 0
 
     jax.lax.fori_loop(0, k, gather_row, 0)
     fused = jnp.dot(w_ref[...], z_ref[...].astype(jnp.float32),
                     preferred_element_type=jnp.float32)  # [1, TD]
-    pl.store(out_ref, (pl.dslice(k, 1), slice(None)),
-             fused.astype(out_ref.dtype))
+    out_ref[pl.dslice(k, 1), :] = fused.astype(out_ref.dtype)
 
 
 def token_drop_pallas(z: jax.Array, keep_idx: jax.Array,

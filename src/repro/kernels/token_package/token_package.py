@@ -38,8 +38,7 @@ def _token_package_kernel(keep_idx_ref, z_ref, w_ref, out_ref, *, k: int):
     def gather_row(r, _):
         idx = keep_idx_ref[r]
         row = z_ref[pl.dslice(idx, 1), :]
-        pl.store(out_ref, (pl.dslice(r, 1), slice(None)),
-                 row.astype(out_ref.dtype))
+        out_ref[pl.dslice(r, 1), :] = row.astype(out_ref.dtype)
         return 0
 
     jax.lax.fori_loop(0, k, gather_row, 0)
@@ -47,8 +46,7 @@ def _token_package_kernel(keep_idx_ref, z_ref, w_ref, out_ref, *, k: int):
     acc = jnp.dot(w, z_ref[...].astype(jnp.float32),
                   preferred_element_type=jnp.float32)  # [1, TD]
     package = acc / (jnp.sum(w) + 1e-9)
-    pl.store(out_ref, (pl.dslice(k, 1), slice(None)),
-             package.astype(out_ref.dtype))
+    out_ref[pl.dslice(k, 1), :] = package.astype(out_ref.dtype)
 
 
 def token_package_pallas(z: jax.Array, keep_idx: jax.Array,

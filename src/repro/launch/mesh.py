@@ -9,7 +9,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False,
@@ -20,12 +20,15 @@ def make_production_mesh(*, multi_pod: bool = False,
     if devices is not None:
         import numpy as np
         return Mesh(np.asarray(devices).reshape(shape), axes)
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """Arbitrary mesh (tests / elastic replans / degraded runs)."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh (tests / elastic replans / degraded runs). Axes are
+    Auto-typed: the sharding rules place arrays with NamedShardings and
+    leave propagation to the compiler."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def required_devices(multi_pod: bool) -> int:

@@ -26,6 +26,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.obs import MetricsRegistry, Tracer
 from repro.serving import ElasticContext, EngineConfig, Request, ServeEngine
@@ -110,7 +111,10 @@ def main():
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--kv-prune", type=float, default=1.0)
     ap.add_argument("--max-batch", type=int, default=4)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the tiny CPU-test preset of --arch; "
+                         "--no-reduced serves its published widths")
     ap.add_argument("--continuous", action="store_true",
                     help="serve through the slot-based continuous path")
     ap.add_argument("--no-slot-prefill", action="store_true",
@@ -136,6 +140,7 @@ def main():
     ap.add_argument("--json", action="store_true",
                     help="print a machine-readable result line")
     args = ap.parse_args()
+    enable_compile_cache()
     out = serve(args.arch, args.requests, args.prompt_len, args.max_new,
                 args.kv_prune, args.reduced, max_batch=args.max_batch,
                 continuous=args.continuous, elastic_drop=args.elastic_drop,

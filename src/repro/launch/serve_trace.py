@@ -27,6 +27,7 @@ import json
 import jax
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.obs import MetricsRegistry, Tracer
 from repro.serving import (EngineConfig, ServeEngine, VisionEngine,
@@ -119,6 +120,7 @@ def main():
                          "scheduler counters) to PATH")
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.trace:
         trace = load_trace(args.trace)

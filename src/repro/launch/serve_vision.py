@@ -4,7 +4,8 @@
     PYTHONPATH=src python -m repro.launch.serve_vision --requests 16 \\
         --slots 4 --planner full --policy prune_pressure_aware
 
-Builds the reduced DeiT config, runs the paper's simultaneous pruning
+Builds the DeiT config (reduced by default; ``--no-reduced`` serves the
+published widths), runs the paper's simultaneous pruning
 offline (init scores -> hard masks -> SBMM packing), then serves a mixed
 stream of image resolutions and per-request token keep rates through the
 continuous-batching engine. ``--planner`` selects the execution-planning
@@ -29,6 +30,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.models import pruning_glue as PG
 from repro.obs import MetricsRegistry, Tracer
@@ -93,8 +95,11 @@ def serve(arch: str = "deit-small", num_requests: int = 16, slots: int = 4,
           planner: str = "full", deadline_ms: float = 0.0,
           pipeline_depth: int = 1, quality: str = "strict",
           keep_floor: float = 0.4, precision: str = "fp32",
-          trace_out: str = "", metrics_out: str = ""):
-    cfg = get_config(arch).reduced()
+          trace_out: str = "", metrics_out: str = "",
+          reduced: bool = True):
+    cfg = get_config(arch)
+    if reduced:
+        cfg = cfg.reduced()
     if image_size:
         cfg = cfg.replace(image_size=image_size)
     key = jax.random.PRNGKey(seed)
@@ -147,7 +152,11 @@ def main():
                     help="admission policy: fifo | shortest_prompt_first "
                          "| prune_pressure_aware")
     ap.add_argument("--image-size", type=int, default=0,
-                    help="override the reduced config's image size")
+                    help="override the config's image size")
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the tiny CPU-test preset of --arch; "
+                         "--no-reduced serves its published widths")
     ap.add_argument("--arrival-spread", type=int, default=4)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--pipeline-depth", type=int, default=1,
@@ -181,12 +190,14 @@ def main():
     ap.add_argument("--json", action="store_true",
                     help="print a machine-readable result line")
     args = ap.parse_args()
+    enable_compile_cache()
     out = serve(args.arch, args.requests, args.slots, args.mode,
                 args.token_tile, args.policy, args.image_size,
                 args.arrival_spread, args.seed, args.planner,
                 args.deadline_ms, args.pipeline_depth, args.quality,
                 args.keep_floor, precision=args.precision,
-                trace_out=args.trace_out, metrics_out=args.metrics_out)
+                trace_out=args.trace_out, metrics_out=args.metrics_out,
+                reduced=args.reduced)
     if args.json:
         print(json.dumps({
             "top1": {str(u): int(np.argmax(lg))

@@ -24,6 +24,7 @@ from repro.configs import get_config
 from repro.configs.base import ShapeConfig
 from repro.data import DataConfig, synthetic_lm_batch
 from repro.dist.fault import FaultConfig, RestartableLoop
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.models import steps as ST
 from repro.models import pruning_glue as PG
@@ -121,6 +122,7 @@ def main():
     ap.add_argument("--prune", action="store_true",
                     help="enable the paper's block weight pruning")
     args = ap.parse_args()
+    enable_compile_cache()
     out = train(args.arch, args.steps, args.batch, args.seq, args.lr,
                 args.ckpt, args.reduced, prune=args.prune)
     if "losses" in out:

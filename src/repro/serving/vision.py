@@ -49,6 +49,8 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core import packed_runner as PR
 from repro.core import quant as Q
+from repro.kernels.backend import default_interpret
+from repro.kernels.sbmm.sbmm import FP16_UNSUPPORTED
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import NULL_TRACER, Tracer
 from repro.serving.planner import (PLANNER_MODES, PlanItem, TileCostModel,
@@ -195,6 +197,8 @@ class VisionEngine:
                              f"got {cfg.family!r}")
         self.cfg = cfg
         self.vc = vc if vc is not None else VisionEngineConfig()
+        if self.vc.precision == "fp16" and not default_interpret():
+            raise ValueError(FP16_UNSUPPORTED)
         # the engine stages a fresh padded batch per tile and never
         # re-reads a dispatched one, so layers tiles can donate their
         # input buffers to the output allocation

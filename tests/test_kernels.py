@@ -167,6 +167,31 @@ def test_backend_env_override(monkeypatch):
         backend.default_interpret()
 
 
+def test_forced_interpret_raises_on_tpu(monkeypatch):
+    """On a TPU backend the served kernels always compile: an env var that
+    forces the interpreter there is an error, while auto and compiled
+    resolve to compiled Pallas."""
+    from repro.kernels import backend
+
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    for forced in ("1", "interpret"):
+        monkeypatch.setenv(backend.ENV_VAR, forced)
+        with pytest.raises(RuntimeError, match="REPRO_KERNEL_INTERPRET"):
+            backend.default_interpret()
+    for mode in ("auto", "compiled"):
+        monkeypatch.setenv(backend.ENV_VAR, mode)
+        assert backend.default_interpret() is False
+
+
+def test_compiled_sbmm_needs_lane_aligned_tile():
+    """The compiled kernel puts the token tile on the 128 lanes; a tile
+    that is not a multiple of 128 is refused before lowering."""
+    pw = packing.pack_weight(np.ones((32, 32), np.float32),
+                             np.ones((2, 2), bool), 16)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        sbmm(jnp.ones((8, 32)), pw, tm=64, interpret=False)
+
+
 def test_kernels_honor_env_interpret(monkeypatch):
     """The non-jitted entry points resolve the env override per call (the
     resolved value is a static jit arg, so flipping the env re-dispatches

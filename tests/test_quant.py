@@ -501,3 +501,22 @@ def test_engine_config_validation():
         VisionEngineConfig(precision="int4")
     with pytest.raises(ValueError):
         VisionEngineConfig(quant_granularity="tensor")
+
+
+def test_fp16_tier_refused_where_kernels_compile(small_model, monkeypatch):
+    """Compiled Pallas cannot load float16 blocks on the TPU, so the fp16
+    tier fails when the engine is built, and a direct kernel call fails
+    before lowering: neither falls back to another tier or the
+    interpreter."""
+    from repro.kernels import backend
+    cfg, masked, packed = small_model
+    monkeypatch.setenv(backend.ENV_VAR, "compiled")
+    with pytest.raises(ValueError, match="float16"):
+        VisionEngine(cfg, masked, packed,
+                     vc=VisionEngineConfig(max_batch=2, precision="fp16"))
+    pw16 = Q.quantize_packed(_packed(jax.random.PRNGKey(3)), "fp16")
+    with pytest.raises(ValueError, match="float16"):
+        sbmm(jnp.ones((8, 64), jnp.float32), pw16)
+    # the int8 tier and fp32 still build
+    VisionEngine(cfg, masked, packed,
+                 vc=VisionEngineConfig(max_batch=2, precision="int8"))
