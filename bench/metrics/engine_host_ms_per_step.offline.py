@@ -1,0 +1,13 @@
+"""Per-layer metric `engine_host_ms_per_step.offline`: host ms of the engine's
+plan, stage and dispatch spans per engine step, offline cells.
+"""
+from harness import readers
+
+LAYER = "serving engine host (serving/vision.py, serving/pipeline.py)"
+UNIT = "ms"
+SOURCE = "program_span"
+MOVES = "images_per_s"
+
+
+def read(ctx):
+    return readers.host_ms_per_step(ctx)
