@@ -33,7 +33,7 @@ zero TDM score, so batching never leaks padding into a request's logits.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 import jax
@@ -441,6 +441,13 @@ def masked_dense_reference(cfg: ModelConfig, params: Dict, scores: Dict,
 # ===========================================================================
 # Jitted segment executor (the vision serving engine's ModelRunner analog)
 # ===========================================================================
+def _program(name: str, fn: Callable, **jit_kwargs):
+    """``jax.jit`` of ``fn`` under ``name``: the compiled module, and so
+    its events on the device trace, is called ``jit_<name>``."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **jit_kwargs)
+
+
 class PackedVitSegments:
     """Owns the jitted per-segment step functions for one
     (cfg, params, packed) triple, behind a compile ledger.
@@ -481,26 +488,34 @@ class PackedVitSegments:
         # so both satisfy it; keep the default off for ad-hoc callers
         # that reuse inputs across calls (e.g. timing probes).
         don = dict(donate_argnums=(2,)) if donate_activations else {}
-        self._embed = jax.jit(
+        # each segment program under its own name, so the device trace
+        # names its module jit_<name> (jit_vit_layers, jit_vit_lane, ...)
+        self._embed = _program(
+            "vit_embed",
             lambda params, patches: vit_embed(cfg, params, patches))
-        self._layers = jax.jit(
+        self._layers = _program(
+            "vit_layers",
             lambda params, packed, x, n_valid, lo, hi, prec: vit_layers(
                 cfg, params, packed, x, lo, hi, n_valid=n_valid,
                 precision=prec),
             static_argnames=("lo", "hi", "prec"), **don)
-        self._tdm = jax.jit(
+        self._tdm = _program(
+            "vit_tdm",
             lambda params, packed, x, n_valid, layer, k, prec: vit_tdm_layer(
                 cfg, params, packed, x, layer, k=k, n_valid=n_valid,
                 precision=prec),
             static_argnames=("layer", "k", "prec"))
-        self._tdm_soft = jax.jit(
+        self._tdm_soft = _program(
+            "vit_tdm_soft",
             lambda params, packed, x, n_valid, pkg_mass, layer, k, prec:
             vit_tdm_soft_layer(cfg, params, packed, x, layer, k=k,
                                pkg_mass=pkg_mass, n_valid=n_valid,
                                precision=prec),
             static_argnames=("layer", "k", "prec"))
-        self._head = jax.jit(lambda params, x: vit_head(cfg, params, x))
-        self._fused = jax.jit(
+        self._head = _program(
+            "vit_head", lambda params, x: vit_head(cfg, params, x))
+        self._fused = _program(
+            "vit_lane",
             lambda params, packed, x, pkg_mass, steps, prec: run_fused_steps(
                 cfg, params, packed, x, steps, pkg_mass=pkg_mass,
                 precision=prec),

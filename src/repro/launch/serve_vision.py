@@ -19,6 +19,8 @@ with the LM path (fifo / shortest_prompt_first / prune_pressure_aware);
 ``--quality`` / ``--keep-floor`` turn on the QualityController (graceful
 quality degradation: keep rates tighten down a quantized grid under
 queue/deadline pressure — ``strict``, the default, is bit-exact off).
+``--profile-dir DIR`` traces the serve with ``jax.profiler`` and writes the
+engine's spans into that trace as annotations, on the device's clock.
 """
 from __future__ import annotations
 
@@ -96,7 +98,7 @@ def serve(arch: str = "deit-small", num_requests: int = 16, slots: int = 4,
           pipeline_depth: int = 1, quality: str = "strict",
           keep_floor: float = 0.4, precision: str = "fp32",
           trace_out: str = "", metrics_out: str = "",
-          reduced: bool = True):
+          reduced: bool = True, profile_dir: str = ""):
     cfg = get_config(arch)
     if reduced:
         cfg = cfg.reduced()
@@ -112,13 +114,21 @@ def serve(arch: str = "deit-small", num_requests: int = 16, slots: int = 4,
                             pipeline_depth=pipeline_depth,
                             quality=quality, keep_floor=keep_floor,
                             precision=precision)
-    tracer = Tracer() if trace_out else None
+    tracer = None
+    if trace_out or profile_dir:
+        tracer = Tracer(annotate=bool(profile_dir))
     engine = VisionEngine.from_pruned(cfg, params, scores, vc=vc,
                                       policy=policy, tracer=tracer)
     reqs = make_requests(cfg, num_requests, arrival_spread, seed,
                          deadline_ms=deadline_ms or None)
+    if profile_dir:
+        jax.profiler.start_trace(profile_dir)
     t0 = time.time()
-    out = engine.serve(reqs)
+    try:
+        out = engine.serve(reqs)
+    finally:
+        if profile_dir:
+            jax.profiler.stop_trace()
     dt = time.time() - t0
     if trace_out:
         tracer.write_chrome_trace(trace_out)
@@ -184,6 +194,11 @@ def main():
                     help="write a Chrome trace_event JSON (Perfetto-"
                          "loadable) of the run's plan/stage/dispatch/"
                          "complete spans to PATH at exit")
+    ap.add_argument("--profile-dir", default="", metavar="DIR",
+                    help="trace the serve with the JAX profiler into DIR "
+                         "(TensorBoard/XProf layout), with the engine's "
+                         "spans as annotations on the device trace's "
+                         "clock")
     ap.add_argument("--metrics-out", default="", metavar="PATH",
                     help="write the engine's metrics-registry snapshot "
                          "(JSON) to PATH at exit")
@@ -197,7 +212,7 @@ def main():
                 args.deadline_ms, args.pipeline_depth, args.quality,
                 args.keep_floor, precision=args.precision,
                 trace_out=args.trace_out, metrics_out=args.metrics_out,
-                reduced=args.reduced)
+                reduced=args.reduced, profile_dir=args.profile_dir)
     if args.json:
         print(json.dumps({
             "top1": {str(u): int(np.argmax(lg))

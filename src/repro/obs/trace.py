@@ -14,6 +14,16 @@ One tracer serves two clocks:
   Scheduler event stream — so the exported trace is byte-identical at any
   pipeline depth (PR-8's timestamp guarantee, now visible in Perfetto).
 
+With ``annotate=True`` every ``begin``/``end`` also enters and exits a
+``jax.profiler.TraceAnnotation`` of the span's plain name, so the
+program's spans land on the device trace's clock whenever a profiler
+session is running (``jax`` is imported only then: ``repro.obs`` stays
+stdlib-only). One annotation stack serves every track, so spans must nest
+LIFO across tracks, not only per track. :meth:`Tracer.record` logs a span
+that is already closed (a request's time in the queue, say) from two
+``time.perf_counter()`` readings; it becomes a Chrome ``X`` event and is
+never annotated.
+
 Export targets:
 
 * :meth:`Tracer.chrome_trace` / :meth:`write_chrome_trace` — Chrome
@@ -38,6 +48,7 @@ trace-schema step.
 """
 from __future__ import annotations
 
+import heapq
 import json
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -88,12 +99,19 @@ class Tracer:
 
     enabled: bool
 
-    def __init__(self, enabled: bool = True):
+    def __init__(self, enabled: bool = True, annotate: bool = False):
         self.enabled = enabled
         self._t0 = time.perf_counter()
-        # chrome events in emission order: (ph, name, tid, ts_us, attrs)
+        # chrome events in emission order: (ph, name, tid, ts_us, attrs,
+        # dur_us); dur_us is set on X events only
         self._events: List[Tuple[str, str, int, float,
-                                 Optional[Dict[str, Any]]]] = []
+                                 Optional[Dict[str, Any]],
+                                 Optional[float]]] = []
+        self._annotation = None
+        if annotate:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+        self._annotations: List[Any] = []  # open annotations, all tracks
         self._tracks: Dict[str, int] = {}      # track name -> tid
         self._stacks: Dict[int, List[Tuple[str, float,
                                            Optional[Dict[str, Any]]]]] = {}
@@ -120,8 +138,12 @@ class Tracer:
         ts = self._ts_us(t_ms)
         tid = self._tid(track)
         a = attrs or None
-        self._events.append(("B", name, tid, ts, a))
+        self._events.append(("B", name, tid, ts, a, None))
         self._stacks.setdefault(tid, []).append((name, ts, a))
+        if self._annotation is not None:
+            ann = self._annotation(name)
+            ann.__enter__()
+            self._annotations.append(ann)
 
     def end(self, name: Optional[str] = None, track: str = "main",
             t_ms: Optional[float] = None) -> None:
@@ -145,11 +167,32 @@ class Tracer:
             stack.append((top, ts0, attrs))
             raise ValueError(f"span {top!r} on track {track!r} ends at "
                              f"{ts}us before it began at {ts0}us")
-        self._events.append(("E", top, tid, ts, None))
+        self._events.append(("E", top, tid, ts, None, None))
         self._spans.append({"name": top, "track": track,
                             "ts_ms": ts0 / 1e3,
                             "dur_ms": (ts - ts0) / 1e3,
                             "attrs": attrs or {}})
+        if self._annotation is not None:
+            self._annotations.pop().__exit__(None, None, None)
+
+    def record(self, name: str, start_s: float, end_s: float,
+               track: str = "main", **attrs: Any) -> None:
+        """Log a span that is already closed, from ``start_s`` to
+        ``end_s`` on ``time.perf_counter()`` (the wall clock's source).
+        It needs no nesting, so spans that overlap or close out of order
+        (requests in a queue) can share a track."""
+        if not self.enabled:
+            return
+        if end_s < start_s:
+            raise ValueError(f"span {name!r} ends at {end_s} before it "
+                             f"began at {start_s}")
+        ts = (start_s - self._t0) * 1e6
+        dur = (end_s - start_s) * 1e6
+        a = attrs or None
+        self._events.append(("X", name, self._tid(track), ts, a, dur))
+        self._spans.append({"name": name, "track": track,
+                            "ts_ms": ts / 1e3, "dur_ms": dur / 1e3,
+                            "attrs": attrs})
 
     def instant(self, name: str, track: str = "main",
                 t_ms: Optional[float] = None, **attrs: Any) -> None:
@@ -157,7 +200,7 @@ class Tracer:
         if not self.enabled:
             return
         self._events.append(("i", name, self._tid(track),
-                             self._ts_us(t_ms), attrs or None))
+                             self._ts_us(t_ms), attrs or None, None))
 
     def span(self, name: str, track: str = "main", **attrs: Any):
         """Wall-clock span context manager (``with tracer.span("plan"):``).
@@ -182,9 +225,11 @@ class Tracer:
 
     # -- export -------------------------------------------------------------
     def chrome_trace(self) -> Dict[str, Any]:
-        """The Chrome ``trace_event`` JSON document (Perfetto-loadable).
-        Raises if any span is still open — an unbalanced trace would fail
-        its own validator."""
+        """The Chrome ``trace_event`` JSON document (Perfetto-loadable),
+        track by track: each track's events in emission order, with its
+        ``X`` events (logged when they closed) merged in at their start
+        times. Raises if any span is still open — an unbalanced trace
+        would fail its own validator."""
         still_open = self.open_spans()
         if still_open:
             raise ValueError(f"cannot export with open spans: {still_open}")
@@ -192,14 +237,24 @@ class Tracer:
         for track, tid in sorted(self._tracks.items(), key=lambda kv: kv[1]):
             events.append({"ph": "M", "name": "thread_name", "pid": 1,
                            "tid": tid, "args": {"name": track}})
-        for ph, name, tid, ts, attrs in self._events:
-            ev: Dict[str, Any] = {"ph": ph, "name": name, "pid": 1,
-                                  "tid": tid, "ts": ts}
-            if ph == "i":
-                ev["s"] = "t"
-            if attrs:
-                ev["args"] = attrs
-            events.append(ev)
+        by_tid: Dict[int, Tuple[List, List]] = {}
+        for ev in self._events:
+            spans, closed = by_tid.setdefault(ev[2], ([], []))
+            (closed if ev[0] == "X" else spans).append(ev)
+        for tid in sorted(by_tid):
+            spans, closed = by_tid[tid]
+            closed.sort(key=lambda ev: ev[3])
+            for ph, name, _, ts, attrs, dur in heapq.merge(
+                    spans, closed, key=lambda ev: ev[3]):
+                ev: Dict[str, Any] = {"ph": ph, "name": name, "pid": 1,
+                                      "tid": tid, "ts": ts}
+                if ph == "i":
+                    ev["s"] = "t"
+                elif ph == "X":
+                    ev["dur"] = dur
+                if attrs:
+                    ev["args"] = attrs
+                events.append(ev)
         return {"displayTimeUnit": "ms", "traceEvents": events}
 
     def write_chrome_trace(self, path: str) -> None:
@@ -226,6 +281,9 @@ class NullTracer(Tracer):
         pass
 
     def end(self, name=None, track="main", t_ms=None):
+        pass
+
+    def record(self, name, start_s, end_s, track="main", **attrs):
         pass
 
     def instant(self, name, track="main", t_ms=None, **attrs):

@@ -123,8 +123,10 @@ class StepPipeline:
             raise ValueError(f"pipeline depth must be >= 1, got {depth}")
         self.depth = depth
         # wall-clock span tracer (repro.obs): dispatch/complete spans on
-        # the "pipeline" track. Disabled by default — one attribute check
-        # per phase; it observes timing only, never reorders work
+        # the "pipeline" track, complete split into block (waiting for the
+        # device) and to_host (the step's complete callback). Disabled by
+        # default — one attribute check per phase; it observes timing
+        # only, never reorders work
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self._inflight: Deque[StagedStep] = deque()
         # accounting (the bench's wall_vs_device column reads these)
@@ -199,13 +201,18 @@ class StepPipeline:
             self.overlap_hits += 1
         if tr.enabled:
             tr.begin("complete", track="pipeline", label=step.label)
+            tr.begin("block", track="pipeline")
         t0 = time.perf_counter()
         jax.block_until_ready(step.handles)
         block = time.perf_counter() - t0
         self.block_s += block
+        if tr.enabled:
+            tr.end("block", track="pipeline")
+            tr.begin("to_host", track="pipeline")
         step.complete(step.handles)
         step.completed = True
         if tr.enabled:
+            tr.end("to_host", track="pipeline")
             tr.end("complete", track="pipeline")
         if step.modeled_ms > 0.0:
             # dispatch wall + block wall brackets the device's work for

@@ -94,6 +94,8 @@ class VisionRequest:
     solo_ms: Optional[float] = None  # modeled solo latency (engine-set)
     submit_t: Optional[float] = None  # monotonic submit time (engine-set;
     # waiting time consumes deadline slack in the refresh)
+    enqueue_s: Optional[float] = None  # perf_counter enqueue time
+    # (engine-set while tracing: the start of the request's queued span)
 
     @property
     def n_patches(self) -> int:
@@ -160,6 +162,44 @@ class VisionEngineConfig:
 
 
 @dataclasses.dataclass
+class DeviceOps:
+    """Device work the engine issues outside its segment programs, for one
+    step or in total. Each eager ``jnp`` call is a device program of its
+    own (``jit_<primitive>`` on the device trace), so the counts follow
+    what each call launches:
+
+    * ``jnp.pad``: one program, and its fill value is one put;
+    * ``jnp.zeros`` of a row: two programs (convert the fill value,
+      broadcast it), one put; of a scalar: one program, one put;
+    * ``jnp.stack`` of m arrays: one ``expand_dims`` each, then
+      concatenates in groups of 16 (:func:`_stack_programs`);
+    * ``y[b, :n]`` (or ``y[b]``): a ``dynamic_slice`` and a ``squeeze``,
+      and each start index (one per axis of ``y``) is one put;
+    * ``x[None]`` and ``reshape``: one program;
+    * a host array made a device array (patches, ``n_valid``): one put.
+    """
+    eager_ops: int = 0   # eager device programs
+    h2d_puts: int = 0    # host-to-device copies
+    to_host: int = 0     # logit rows copied back to the host
+
+    def add(self, other: "DeviceOps") -> None:
+        self.eager_ops += other.eager_ops
+        self.h2d_puts += other.h2d_puts
+        self.to_host += other.to_host
+
+
+def _stack_programs(m: int) -> int:
+    """Device programs ``jnp.stack`` launches for ``m`` arrays: an
+    ``expand_dims`` per array, then rounds of concatenates over groups of
+    16 until one array is left (a group of one passes through)."""
+    n = m
+    while m > 1:
+        n += m // 16 + (m % 16 > 1)
+        m = -(-m // 16)
+    return n
+
+
+@dataclasses.dataclass
 class _Live:
     """Per-slot in-flight state: the request, its current activation
     (unpadded — padding is a per-tile concern) and where it is in the
@@ -176,6 +216,7 @@ class _Live:
     pkg_mass: Any = None  # accumulated package mass (0-d device array)
     # after the first soft TDM; updated at dispatch like x/n_tokens
     admit_t: float = 0.0  # monotonic admission time (deadline slack base)
+    admit_s: float = 0.0  # perf_counter admission time (tracing only)
     precision: str = "fp32"  # execution precision chosen at admission
     # (planner-priced; "strict" quality pins fp32) — static per request so
     # its stage keys, and therefore its tiles, stay precision-uniform
@@ -238,6 +279,9 @@ class VisionEngine:
         self.plan_ahead_drops = 0
         self.steps = 0
         self.images_served = 0
+        # device work outside the segment programs, summed over completed
+        # steps (always counted; a traced step also logs its own)
+        self.device_ops = DeviceOps()
         # quantization observability: tiles+lanes dispatched per precision,
         # and how many of those went through the dequant-in-kernel int8
         # SBMM path (counted at the dispatch phase, like planner.commit)
@@ -298,9 +342,15 @@ class VisionEngine:
         drivers (``repro.traffic.harness``) call the pieces themselves to
         interleave submission with stepping on their own clock."""
         base = self.steps
-        for r in requests:  # validate ALL before enqueueing ANY: a bad
-            self._validate(r)  # request must not leak its siblings into
-        for r in requests:     # the engine (they'd surface next serve())
+        # validate ALL before enqueueing ANY: a bad request must not leak
+        # its siblings into the engine (they'd surface next serve())
+        for r in requests:
+            self._validate(r)
+        if self.tracer.enabled:
+            now_s = time.perf_counter()
+            for r in requests:
+                r.enqueue_s = now_s
+        for r in requests:
             if r.prune_load is None:
                 sched = self._base_schedule(r)
                 traj = PR.token_trajectory(
@@ -434,6 +484,8 @@ class VisionEngine:
             **{f"dispatch_{p}": n
                for p, n in self.precision_dispatches.items()},
             "dequant_dispatches": self.dequant_dispatches,
+            # eager_ops / h2d_puts / to_host of every completed step
+            **dataclasses.asdict(self.device_ops),
             **{f"sched_{k}": v for k, v in self.scheduler.stats().items()},
             **{f"pipeline_{k}": v for k, v in self.pipeline.stats().items()},
             **{f"batcher_{k}": v for k, v in self.batcher.stats().items()},
@@ -539,6 +591,7 @@ class VisionEngine:
                 schedule=self._base_schedule(req),
                 soft=req.soft_prune,
                 admit_t=time.monotonic(),
+                admit_s=time.perf_counter() if self.tracer.enabled else 0.0,
                 precision=self._precision_for(req, record=True))
 
     def _precision_for(self, r: VisionRequest, record: bool = False) -> str:
@@ -741,7 +794,12 @@ class VisionEngine:
         slots = sorted(self._live)
         now = time.monotonic()
         tr = self.tracer
+        step_no = self.steps
+        ops = DeviceOps()  # this step's device work outside its segments
+        t_step, compiled0 = 0.0, 0
         if tr.enabled:
+            t_step = time.perf_counter()
+            compiled0 = self.segments.jit_compile_count()
             tr.begin("plan", track="engine", step=self.steps,
                      population=len(slots))
         # quality resolution happens ONCE per staging pass, before planning:
@@ -797,10 +855,18 @@ class VisionEngine:
             rows = [jnp.pad(jnp.asarray(st.x, jnp.float32),
                             ((0, tile.n_tile - st.n_tokens), (0, 0)))
                     for st in states]
+            # a pad per member (its fill value is a put), and a put of
+            # each member still on the host
+            ops.eager_ops += len(states)
+            ops.h2d_puts += len(states) + sum(
+                isinstance(st.x, np.ndarray) for st in states)
             if tile.b_tile > len(states):
                 zero = jnp.zeros((tile.n_tile, feat), jnp.float32)
                 rows += [zero] * (tile.b_tile - len(states))
+                ops.eager_ops += 2
+                ops.h2d_puts += 1
             batch = jnp.stack(rows)
+            ops.eager_ops += _stack_programs(tile.b_tile)
             n_valid = None
             if tile.needs_mask and seg[0] in ("layers", "tdm"):
                 n_valid = np.fromiter(
@@ -808,6 +874,7 @@ class VisionEngine:
                 n_valid = np.concatenate(
                     [n_valid, np.full(tile.b_tile - len(states), tile.n_tile,
                                       np.int32)])
+                ops.h2d_puts += 1  # segments.run puts it on the device
             pkg_mass = None
             if soft and self._tdm_before[tile.stage[0]] > 0:
                 # every member past its first soft TDM carries a package
@@ -817,6 +884,9 @@ class VisionEngine:
                      for st in states]
                     + [jnp.zeros((), jnp.float32)]
                     * (tile.b_tile - len(states)))
+                ops.eager_ops += (len(states) + 1
+                                  + _stack_programs(tile.b_tile))
+                ops.h2d_puts += 1
             tile_runs.append((tile, member_slots, seg, k, soft, prec, batch,
                               n_valid, pkg_mass))
 
@@ -832,20 +902,27 @@ class VisionEngine:
             seed = None
             if st.pkg_mass is not None:
                 seed = jnp.asarray(st.pkg_mass, jnp.float32).reshape(1)
+                ops.eager_ops += 1
             lane_runs.append((slot, steps, jnp.asarray(st.x,
                                                        jnp.float32)[None],
                               seed))
+            ops.eager_ops += 1
+            ops.h2d_puts += isinstance(st.x, np.ndarray)
         if tr.enabled:
             tr.end("stage", track="engine")
 
-        produced: List[Any] = []  # (req, y handle, row) head/lane outputs
+        # (state, y handle, row, "lane" | "tile") of head and lane outputs
+        produced: List[Any] = []
 
-        def run_tile(tr):
+        def run_tile(run):
             (tile, member_slots, seg, k, soft, prec, batch, n_valid,
-             pkg_mass) = tr
+             pkg_mass) = run
             self.precision_dispatches[prec] += 1
             if prec == "int8":
                 self.dequant_dispatches += 1
+            if tr.enabled:
+                tr.begin("tile", track="pipeline", seg=seg,
+                         batch=tile.b_tile, n=tile.n_tile, k=k)
             mass = None
             if soft:
                 y, mass = self.segments.run(seg, batch, n_valid=n_valid,
@@ -855,7 +932,18 @@ class VisionEngine:
             else:
                 y = self.segments.run(seg, batch, n_valid=n_valid, k=k,
                                       precision=prec)
+            if tr.enabled:
+                tr.end("tile", track="pipeline")
+                tr.begin("unstage", track="pipeline")
             kind = seg[0]
+            if kind != "head":
+                # a row slice per member (and one of its package mass)
+                m = len(member_slots)
+                ops.eager_ops += 2 * m
+                ops.h2d_puts += y.ndim * m
+                if soft:
+                    ops.eager_ops += 2 * m
+                    ops.h2d_puts += mass.ndim * m
             for b, slot in enumerate(member_slots):
                 st = self._live[slot]
                 if kind == "embed":
@@ -869,8 +957,10 @@ class VisionEngine:
                     if soft:
                         st.pkg_mass = mass[b]
                 else:  # head
-                    produced.append((st.req, y, b))
+                    produced.append((st, y, b, "tile"))
                 st.seg_idx += 1
+            if tr.enabled:
+                tr.end("unstage", track="pipeline")
             return y
 
         def dispatch():
@@ -878,18 +968,23 @@ class VisionEngine:
             # lanes: a fused lane is the most expensive single dispatch of
             # the step and must not sit on a deadline-urgent request's
             # critical path
-            handles = [run_tile(tr) for tr in tile_runs[:n_urgent]]
+            handles = [run_tile(run) for run in tile_runs[:n_urgent]]
             for slot, steps, x1, seed in lane_runs:
                 st = self._live[slot]
                 self.precision_dispatches[st.precision] += 1
                 if st.precision == "int8":
                     self.dequant_dispatches += 1
+                if tr.enabled:
+                    tr.begin("lane", track="pipeline", steps=steps, batch=1,
+                             n=x1.shape[1])
                 y = self.segments.run_fused(steps, x1, pkg_mass=seed,
                                             precision=st.precision)
-                produced.append((st.req, y, 0))
+                if tr.enabled:
+                    tr.end("lane", track="pipeline")
+                produced.append((st, y, 0, "lane"))
                 st.seg_idx = n_segs
                 handles.append(y)
-            handles += [run_tile(tr) for tr in tile_runs[n_urgent:]]
+            handles += [run_tile(run) for run in tile_runs[n_urgent:]]
             self.planner.commit(plan)
             if q.enabled:
                 q.record(q_dec, q_tight, q_levels,
@@ -898,15 +993,44 @@ class VisionEngine:
             return handles
 
         def complete(handles):
-            for req, y, row in produced:
+            for st, y, row, path in produced:
+                req = st.req
                 req.logits = np.asarray(y[row])
                 req.done = True
                 out[req.uid] = req.logits
+                # the row slice, and the copy to the host
+                ops.eager_ops += 2
+                ops.h2d_puts += y.ndim
+                ops.to_host += 1
+                if tr.enabled:
+                    self._record_request(st, path, time.perf_counter())
+            self.device_ops.add(ops)
+            if tr.enabled:
+                tr.record("step", t_step, time.perf_counter(), track="engine",
+                          step=step_no, tiles=len(plan.tiles),
+                          lanes=len(plan.lanes), eager_ops=ops.eager_ops,
+                          h2d_puts=ops.h2d_puts, to_host=ops.to_host,
+                          compiled=(self.segments.jit_compile_count()
+                                    - compiled0))
 
         return StagedStep(dispatch=dispatch, complete=complete,
                           label=f"vit-step-{self.steps}",
                           modeled_ms=self.planner.cost_model.ms(
                               plan.stats.modeled_cycles))
+
+    def _record_request(self, st: _Live, path: str, done_s: float) -> None:
+        """Log a served request's ``queued`` (enqueue to admission) and
+        ``served`` (admission to its logits in the result dict) spans on
+        the ``requests`` track, sharing its uid."""
+        req = st.req
+        attrs = dict(uid=req.uid, path=path,
+                     r_t=self.cfg.pruning.r_t if req.r_t is None
+                     else req.r_t)
+        if req.enqueue_s is not None:
+            self.tracer.record("queued", req.enqueue_s, st.admit_s,
+                               track="requests", **attrs)
+        self.tracer.record("served", st.admit_s, done_s, track="requests",
+                           **attrs)
 
     def _retire_finished(self) -> None:
         """Free slots whose trajectory completed. Host-deterministic given

@@ -22,6 +22,7 @@ import json
 import math
 
 import jax
+import numpy as np
 import pytest
 
 from repro.configs import DEIT_SMALL
@@ -30,7 +31,8 @@ from repro.models import model as M
 from repro.models import pruning_glue as PG
 from repro.obs import (EventLog, MetricsRegistry, NULL_TRACER, NullTracer,
                        Tracer, log_buckets, validate_chrome_trace)
-from repro.serving import Scheduler, VisionEngine, VisionEngineConfig
+from repro.serving import (Scheduler, VisionEngine, VisionEngineConfig,
+                           VisionRequest)
 from repro.traffic import TraceSpec, TrafficHarness, VisionDriver, make_trace
 
 
@@ -372,3 +374,246 @@ def test_artifact_metrics_block_roundtrip(tmp_path):
     path2 = str(tmp_path / "b.json")
     write_bench_artifact(path2, "vision", {}, {})
     assert load_bench_artifact(path2)["metrics"] is None
+
+
+# ===========================================================================
+# closed spans, profiler annotations
+# ===========================================================================
+def test_record_exports_x_events_that_validate():
+    tr = Tracer()
+    t0 = tr._t0
+    tr.begin("outer", track="requests", t_ms=0.0)
+    tr.end("outer", track="requests", t_ms=50.0)
+    # closed spans logged late and out of order, overlapping each other
+    tr.record("served", t0 + 0.030, t0 + 0.040, track="requests", uid=2)
+    tr.record("served", t0 + 0.010, t0 + 0.045, track="requests", uid=1)
+    tr.record("queued", t0 + 0.005, t0 + 0.010, track="requests", uid=1)
+    log = [s for s in tr.span_log if s["name"] != "outer"]
+    assert [s["attrs"]["uid"] for s in log] == [2, 1, 1]
+    assert log[1]["ts_ms"] == pytest.approx(10.0)
+    assert log[1]["dur_ms"] == pytest.approx(35.0)
+    doc = tr.chrome_trace()
+    assert validate_chrome_trace(doc)["spans"] == 1
+    xs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    assert [e["ts"] for e in xs] == sorted(e["ts"] for e in xs)
+    assert xs[0]["name"] == "queued" and xs[0]["dur"] == pytest.approx(5e3)
+    assert xs[0]["args"] == {"uid": 1}
+    with pytest.raises(ValueError, match="before it began"):
+        tr.record("bad", t0 + 1.0, t0 + 0.5)
+    NULL_TRACER.record("x", 0.0, 1.0)
+    assert NULL_TRACER.span_log == []
+
+
+def test_annotate_opens_and_closes_a_profiler_annotation(monkeypatch):
+    seen = []
+
+    class FakeAnnotation:
+        def __init__(self, name, **kw):
+            assert not kw  # the plain span name, no metadata
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", FakeAnnotation)
+    tr = Tracer(annotate=True)
+    tr.begin("dispatch", track="pipeline", label="step-0")
+    with tr.span("tile", track="pipeline", seg=("layers", 0, 1)):
+        pass
+    tr.end("dispatch", track="pipeline")
+    tr.record("queued", tr._t0, tr._t0 + 1e-3)   # closed: not annotated
+    assert seen == [("enter", "dispatch"), ("enter", "tile"),
+                    ("exit", "tile"), ("exit", "dispatch")]
+    with pytest.raises(ValueError, match="does not match"):
+        tr.begin("a")
+        tr.end("b")
+    assert seen[-1] == ("enter", "a")   # a failed end leaves it open
+    tr.end("a")
+    assert seen[-1] == ("exit", "a")
+    plain = Tracer()
+    plain.begin("x")
+    plain.end("x")
+    assert seen[-1] == ("exit", "a")
+
+
+# ===========================================================================
+# VisionEngine spans, records and counters
+# ===========================================================================
+class _OneStackTracer(Tracer):
+    """A tracer whose spans share one LIFO stack across tracks, as a
+    profiler's annotations do."""
+
+    def __init__(self):
+        super().__init__()
+        self.stack = []
+
+    def begin(self, name, track="main", t_ms=None, **attrs):
+        super().begin(name, track=track, t_ms=t_ms, **attrs)
+        self.stack.append(name)
+
+    def end(self, name=None, track="main", t_ms=None):
+        super().end(name, track=track, t_ms=t_ms)
+        assert self.stack.pop() == name
+
+
+def _requests(cfg, mixes):
+    rng = np.random.default_rng(3)
+    pdim = cfg.patch_size ** 2 * 3
+    return [VisionRequest(
+        uid=i, patches=rng.standard_normal((n, pdim)).astype(np.float32),
+        r_t=r_t, arrival_step=arr)
+        for i, (n, r_t, arr) in enumerate(mixes)]
+
+
+MIXED = [(16, 0.5, 0), (16, 0.5, 0), (9, 0.7, 0), (16, None, 1),
+         (4, 0.5, 2), (16, 0.7, 2)]
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_engine_spans_nest_in_one_stack_across_tracks(packed_vit, depth):
+    cfg, masked, packed = packed_vit
+    tr = _OneStackTracer()
+    eng = VisionEngine(cfg, masked, packed, VisionEngineConfig(
+        max_batch=4, planner="full", pipeline_depth=depth), tracer=tr)
+    out = eng.serve(_requests(cfg, MIXED))
+    assert len(out) == len(MIXED) and tr.stack == [] and not tr.open_spans()
+    names = {s["name"] for s in tr.span_log}
+    assert {"plan", "stage", "dispatch", "complete", "tile", "lane",
+            "unstage", "block", "to_host", "queued", "served",
+            "step"} <= names
+    validate_chrome_trace(tr.chrome_trace())
+    tiles = [s["attrs"] for s in tr.span_log if s["name"] == "tile"]
+    assert all(set(a) == {"seg", "batch", "n", "k"} for a in tiles)
+    lanes = [s["attrs"] for s in tr.span_log if s["name"] == "lane"]
+    assert lanes and all(a["batch"] == 1 and a["steps"] for a in lanes)
+
+
+def test_request_records_share_uid_and_order(packed_vit):
+    cfg, masked, packed = packed_vit
+    tr = Tracer()
+    eng = VisionEngine(cfg, masked, packed, VisionEngineConfig(
+        max_batch=2, planner="full"), tracer=tr)
+    reqs = _requests(cfg, MIXED)
+    eng.serve(reqs)
+    by = {}
+    for s in tr.span_log:
+        if s["name"] in ("queued", "served"):
+            assert s["track"] == "requests"
+            by.setdefault(s["attrs"]["uid"], {}).setdefault(
+                s["name"], []).append(s)
+    assert sorted(by) == [r.uid for r in reqs]
+    for r in reqs:
+        (q,), (s,) = by[r.uid]["queued"], by[r.uid]["served"]
+        assert q["attrs"] == s["attrs"]
+        assert s["attrs"]["path"] in ("lane", "tile")
+        assert s["attrs"]["r_t"] == (cfg.pruning.r_t if r.r_t is None
+                                     else r.r_t)
+        assert q["ts_ms"] + q["dur_ms"] <= s["ts_ms"] + 1e-9
+        assert q["dur_ms"] >= 0 and s["dur_ms"] > 0
+    # two slots for six requests: later arrivals wait for a slot
+    assert max(by[u]["queued"][0]["dur_ms"] for u in by) > 0
+
+
+def test_eager_ops_match_a_hand_count(packed_vit):
+    """Three requests of one size and keep rate at max_batch 4 (planner
+    off): every step runs one 3-member tile padded with a zero row."""
+    cfg, masked, packed = packed_vit
+    tr = Tracer()
+    eng = VisionEngine(cfg, masked, packed, VisionEngineConfig(
+        max_batch=4), tracer=tr)
+    before = eng.stats()
+    eng.serve(_requests(cfg, [(16, 0.5, 0)] * 3))
+    after = eng.stats()
+    steps = [s["attrs"] for s in tr.span_log if s["name"] == "step"]
+    kinds = [seg[0] for seg in eng.segments.plan]
+    assert [a["step"] for a in steps] == list(range(len(kinds)))
+    # per step: a pad per member (3), a zero row (convert + broadcast: 2),
+    # the stack of 4 rows (4 expand_dims + 1 concatenate), and a row
+    # slice per member (dynamic_slice + squeeze: 6), unstaged after the
+    # segment or, after the head, copied to the host
+    assert [a["eager_ops"] for a in steps] == [3 + 2 + 5 + 6] * len(kinds)
+    # puts: the patches at the embed step (3), the pads' fill values (3),
+    # the zero row's (1), and a start index per axis of each row slice
+    # (3-d activations, 2-d logits)
+    puts = {"embed": 3 + 3 + 1 + 3 * 3, "layers": 3 + 1 + 3 * 3,
+            "tdm": 3 + 1 + 3 * 3, "head": 3 + 1 + 3 * 2}
+    assert [a["h2d_puts"] for a in steps] == [puts[k] for k in kinds]
+    assert [a["to_host"] for a in steps] == [3 if k == "head" else 0
+                                             for k in kinds]
+    assert all(a["tiles"] == 1 and a["lanes"] == 0 for a in steps)
+    # a fresh engine compiles each segment at its first step, and the
+    # same requests served again compile nothing
+    assert [a["compiled"] for a in steps] == [1] * len(kinds)
+    for key in ("eager_ops", "h2d_puts", "to_host"):
+        assert after[key] - before[key] == sum(a[key] for a in steps)
+    n = len(tr.span_log)
+    eng.serve(_requests(cfg, [(16, 0.5, 0)] * 3))
+    again = [s["attrs"] for s in tr.span_log[n:] if s["name"] == "step"]
+    assert [a["compiled"] for a in again] == [0] * len(kinds)
+
+
+def test_engine_counts_device_ops_with_tracing_off(packed_vit):
+    cfg, masked, packed = packed_vit
+    eng = VisionEngine(cfg, masked, packed, VisionEngineConfig(max_batch=4))
+    eng.serve(_requests(cfg, [(16, 0.5, 0)] * 3))
+    n = len(eng.segments.plan)
+    st = eng.stats()
+    assert st["eager_ops"] == 16 * n and st["to_host"] == 3
+
+
+def test_tracing_keeps_logits_bitexact(packed_vit):
+    cfg, masked, packed = packed_vit
+    outs = []
+    for tracer in (None, _OneStackTracer()):
+        eng = VisionEngine(cfg, masked, packed, VisionEngineConfig(
+            max_batch=4, planner="full"), tracer=tracer)
+        outs.append(eng.serve(_requests(cfg, MIXED)))
+    assert sorted(outs[0]) == sorted(outs[1])
+    for uid in outs[0]:
+        np.testing.assert_array_equal(outs[0][uid], outs[1][uid])
+
+
+def test_segment_programs_lower_to_named_modules(packed_vit):
+    import jax.numpy as jnp
+    cfg, masked, packed = packed_vit
+    seg = PR.PackedVitSegments(cfg, masked, packed)
+    P = cfg.patch_size ** 2 * 3
+    D = cfg.d_model
+    x = jnp.zeros((1, 5, D), jnp.float32)
+    pk = seg.packed_for("fp32")
+    tdm = next(s for s in seg.plan if s[0] == "tdm")
+    embed_steps = tuple((s, PR.tdm_keep_count(5, 0.5) if s[0] == "tdm"
+                         else None) for s in seg.plan)
+    lowered = {
+        "vit_embed": seg._embed.lower(masked, jnp.zeros((1, 4, P))),
+        "vit_layers": seg._layers.lower(masked, pk, x, None, lo=0, hi=1,
+                                        prec="fp32"),
+        "vit_tdm": seg._tdm.lower(masked, pk, x, None, layer=tdm[1], k=2,
+                                  prec="fp32"),
+        "vit_tdm_soft": seg._tdm_soft.lower(masked, pk, x, None, None,
+                                            layer=tdm[1], k=2, prec="fp32"),
+        "vit_head": seg._head.lower(masked, x),
+        "vit_lane": seg._fused.lower(masked, pk, jnp.zeros((1, 4, P)), None,
+                                     steps=embed_steps, prec="fp32"),
+    }
+    for name, low in lowered.items():
+        assert low.as_text().startswith(f"module @jit_{name} "), name
+
+
+def test_serve_vision_profile_dir_puts_spans_on_the_profile(tmp_path):
+    import glob
+    from jax.profiler import ProfileData
+    from repro.launch.serve_vision import serve
+    out = serve(num_requests=3, slots=2, arrival_spread=0,
+                profile_dir=str(tmp_path))
+    assert len(out["outputs"]) == 3
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    assert {"plan", "stage", "dispatch", "complete", "block",
+            "to_host"} <= names
