@@ -483,9 +483,10 @@ class PackedVitSegments:
         # [B, n, D] input->output, so only its input tile is donatable
         # (embed/tdm/head change shapes — donating them would just warn
         # and allocate anyway). Donation requires callers never to re-read
-        # a dispatched tile: the serving engine stages a fresh padded
-        # batch per tile and forward_vit_packed rebinds x each segment,
-        # so both satisfy it; keep the default off for ad-hoc callers
+        # a dispatched tile: the serving engine's tiles are fresh padded
+        # batches, or an output passed through whole whose rows all move
+        # on, and forward_vit_packed rebinds x each segment, so both
+        # satisfy it; keep the default off for ad-hoc callers
         # that reuse inputs across calls (e.g. timing probes).
         don = dict(donate_argnums=(2,)) if donate_activations else {}
         # each segment program under its own name, so the device trace

@@ -195,7 +195,11 @@ class StepPipeline:
     def _complete_oldest(self) -> None:
         step = self._inflight.popleft()
         tr = self.tracer
-        leaves = jax.tree_util.tree_leaves(step.handles)
+        # a later step may already have consumed (donated) an output of
+        # this one; the device orders that work before the later step's,
+        # so the rest of the outputs say when this step is done
+        leaves = [l for l in jax.tree_util.tree_leaves(step.handles)
+                  if not (hasattr(l, "is_deleted") and l.is_deleted())]
         if leaves and all(l.is_ready() for l in leaves
                           if hasattr(l, "is_ready")):
             self.overlap_hits += 1
@@ -203,7 +207,7 @@ class StepPipeline:
             tr.begin("complete", track="pipeline", label=step.label)
             tr.begin("block", track="pipeline")
         t0 = time.perf_counter()
-        jax.block_until_ready(step.handles)
+        jax.block_until_ready(leaves)
         block = time.perf_counter() - t0
         self.block_s += block
         if tr.enabled:

@@ -176,16 +176,25 @@ class DeviceOps:
     * ``y[b, :n]`` (or ``y[b]``): a ``dynamic_slice`` and a ``squeeze``,
       and each start index (one per axis of ``y``) is one put;
     * ``x[None]`` and ``reshape``: one program;
-    * a host array made a device array (patches, ``n_valid``): one put.
+    * a host array made a device array (a batch of patches stacked on the
+      host, a lane's patches, ``n_valid``): one put.
+
+    A tile passed through (the previous tile's output, whole) launches
+    nothing. A member's rows are sliced out of a tile's output only when
+    a later tile or lane cannot take that output whole, so the slice is
+    counted in the step that stages it. Logits reach the host as one
+    copy of each head or lane output, which launches nothing either.
     """
     eager_ops: int = 0   # eager device programs
     h2d_puts: int = 0    # host-to-device copies
-    to_host: int = 0     # logit rows copied back to the host
+    to_host: int = 0     # logit rows delivered to the host
+    passthrough_tiles: int = 0  # tiles staged as the previous output whole
 
     def add(self, other: "DeviceOps") -> None:
         self.eager_ops += other.eager_ops
         self.h2d_puts += other.h2d_puts
         self.to_host += other.to_host
+        self.passthrough_tiles += other.passthrough_tiles
 
 
 def _stack_programs(m: int) -> int:
@@ -199,6 +208,22 @@ def _stack_programs(m: int) -> int:
     return n
 
 
+@dataclasses.dataclass(frozen=True)
+class _Rows:
+    """Row ``b`` of the output ``y`` of a tile: where a member's value
+    lives once its tile ran. Kept as a reference, so that a tile made of
+    exactly that output's rows takes ``y`` whole, and other paths slice
+    it only when they need the member on its own."""
+    y: Any
+    b: int
+
+    def take(self, n: Optional[int], ops: DeviceOps):
+        """``y[b, :n]`` (``y[b]`` for ``n=None``), counted into ``ops``."""
+        ops.eager_ops += 2
+        ops.h2d_puts += self.y.ndim
+        return self.y[self.b] if n is None else self.y[self.b, :n]
+
+
 @dataclasses.dataclass
 class _Live:
     """Per-slot in-flight state: the request, its current activation
@@ -206,15 +231,17 @@ class _Live:
     segment plan."""
     req: VisionRequest
     seg_idx: int
-    x: Any               # patches (pre-embed) or [n_tokens, D] activations
+    x: Any               # host patches (pre-embed), or the _Rows of the
+    # tile output holding its [n_tokens, D] activations
     n_tokens: int        # real rows of x (grouping key)
     schedule: Tuple[float, ...]  # BASE per-TDM keep schedule (static per
     # request; the QualityController resolves the *effective* schedule
     # from it at every staging pass — already-executed entries are baked
     # into n_tokens and never revisited)
     soft: bool = False   # package-token soft TDM for this request
-    pkg_mass: Any = None  # accumulated package mass (0-d device array)
-    # after the first soft TDM; updated at dispatch like x/n_tokens
+    pkg_mass: Any = None  # accumulated package mass (_Rows of a soft TDM
+    # tile's [B] mass) after the first soft TDM; updated at dispatch like
+    # x/n_tokens
     admit_t: float = 0.0  # monotonic admission time (deadline slack base)
     admit_s: float = 0.0  # perf_counter admission time (tracing only)
     precision: str = "fp32"  # execution precision chosen at admission
@@ -240,9 +267,11 @@ class VisionEngine:
         self.vc = vc if vc is not None else VisionEngineConfig()
         if self.vc.precision == "fp16" and not default_interpret():
             raise ValueError(FP16_UNSUPPORTED)
-        # the engine stages a fresh padded batch per tile and never
-        # re-reads a dispatched one, so layers tiles can donate their
-        # input buffers to the output allocation
+        # the engine never re-reads a dispatched tile's batch: it is a
+        # fresh padded stack, or an output passed through whole, whose
+        # every row belongs to the tile's members (each then moves on to
+        # the new output). So layers tiles can donate their input buffers
+        # to the output allocation
         self.segments = PR.PackedVitSegments(
             cfg, params, packed, use_tdm=self.vc.use_tdm,
             donate_activations=True,
@@ -275,6 +304,12 @@ class VisionEngine:
         # match; dropped (and replanned) when admissions/retirements made
         # the prediction stale.
         self._plan_cache: Optional[Any] = None
+        # bucket keys of tiles dispatched from a stacked batch: the first
+        # tile of a bucket is always stacked, so the eager programs a
+        # ragged tile may later need (row slices of this bucket's inputs,
+        # pads, the stack) compile with its segment program, at warm-up,
+        # and not in a later step
+        self._stacked: set = set()
         self.plan_ahead_hits = 0
         self.plan_ahead_drops = 0
         self.steps = 0
@@ -484,7 +519,8 @@ class VisionEngine:
             **{f"dispatch_{p}": n
                for p, n in self.precision_dispatches.items()},
             "dequant_dispatches": self.dequant_dispatches,
-            # eager_ops / h2d_puts / to_host of every completed step
+            # eager_ops / h2d_puts / to_host / passthrough_tiles of every
+            # completed step
             **dataclasses.asdict(self.device_ops),
             **{f"sched_{k}": v for k, v in self.scheduler.stats().items()},
             **{f"pipeline_{k}": v for k, v in self.pipeline.stats().items()},
@@ -779,6 +815,24 @@ class VisionEngine:
         return tuple((it.stage, it.n_tokens, it.cap, it.trajectory)
                      for it in items)
 
+    def _passthrough(self, tile, states: List[_Live]):
+        """The batch a lock-step tile already is, else ``None``: a tile of
+        a bucket stacked before, whose members are rows ``0..m-1`` of one
+        tile output ``y``, in tile order, and fill every row and token of
+        the tile (no zero row, no token pad). Then ``y`` is bit for bit
+        what pad and stack would build, and staging it launches
+        nothing."""
+        y = states[0].x.y if isinstance(states[0].x, _Rows) else None
+        if (y is None or tile.bucket_key not in self._stacked
+                or len(states) != tile.b_tile
+                or y.shape != (tile.b_tile, tile.n_tile, self.cfg.d_model)):
+            return None
+        for b, st in enumerate(states):
+            if not (isinstance(st.x, _Rows) and st.x.y is y and st.x.b == b
+                    and st.n_tokens == tile.n_tile):
+                return None
+        return y
+
     def _stage_step(self, out: Dict[int, np.ndarray]) -> StagedStep:
         """Stage one engine step: plan the population, build every tile's
         padded input batch and every lane's entry activation, and close
@@ -787,10 +841,15 @@ class VisionEngine:
         ``planner.commit``) — a staged step can be dropped for a replan
         and leaks nothing.
 
-        Exactness: padding and stacking are pure data movement, so the
-        staged buffers are bitwise the batches the synchronous path
-        built host-side; the same jitted segment bodies then make the
-        logits independent of pipeline depth."""
+        A tile is staged in the cheapest of three ways: the previous
+        tile's output passed through whole (:meth:`_passthrough`), host
+        patches padded and stacked on the host in one put, or each
+        member's rows sliced, padded and stacked on the device.
+
+        Exactness: all three are pure data movement, so the staged
+        buffers are bitwise the batches the synchronous path built
+        host-side; the same jitted segment bodies then make the logits
+        independent of pipeline depth."""
         slots = sorted(self._live)
         now = time.monotonic()
         tr = self.tracer
@@ -848,25 +907,37 @@ class VisionEngine:
             # (si, segment, k[, "soft"][, precision]) — states[0] only
             # supplies data
             seg, k, soft, prec = self._parse_stage(tile.stage)
-            # token/batch padding is exactness-neutral; building the batch
-            # from device handles (pad + stack) keeps staging async — the
-            # old host-side scatter would block on the previous step
-            feat = states[0].x.shape[-1]
-            rows = [jnp.pad(jnp.asarray(st.x, jnp.float32),
-                            ((0, tile.n_tile - st.n_tokens), (0, 0)))
-                    for st in states]
-            # a pad per member (its fill value is a put), and a put of
-            # each member still on the host
-            ops.eager_ops += len(states)
-            ops.h2d_puts += len(states) + sum(
-                isinstance(st.x, np.ndarray) for st in states)
-            if tile.b_tile > len(states):
-                zero = jnp.zeros((tile.n_tile, feat), jnp.float32)
-                rows += [zero] * (tile.b_tile - len(states))
-                ops.eager_ops += 2
+            batch = self._passthrough(tile, states)
+            passed = batch is not None
+            if passed:
+                ops.passthrough_tiles += 1
+            elif all(isinstance(st.x, np.ndarray) for st in states):
+                # host patches: pad and stack on the host, one put
+                batch = np.zeros((tile.b_tile, tile.n_tile,
+                                  states[0].x.shape[-1]), np.float32)
+                for b, st in enumerate(states):
+                    batch[b, : st.n_tokens] = st.x
+                batch = jnp.asarray(batch)
                 ops.h2d_puts += 1
-            batch = jnp.stack(rows)
-            ops.eager_ops += _stack_programs(tile.b_tile)
+            else:
+                # token/batch padding is exactness-neutral; building the
+                # batch from device handles (pad + stack) keeps staging
+                # async — a host-side scatter would block on the
+                # previous step
+                rows = [jnp.pad(st.x.take(st.n_tokens, ops),
+                                ((0, tile.n_tile - st.n_tokens), (0, 0)))
+                        for st in states]
+                # a pad per member (its fill value is a put)
+                ops.eager_ops += len(states)
+                ops.h2d_puts += len(states)
+                if tile.b_tile > len(states):
+                    zero = jnp.zeros((tile.n_tile, rows[0].shape[-1]),
+                                     jnp.float32)
+                    rows += [zero] * (tile.b_tile - len(states))
+                    ops.eager_ops += 2
+                    ops.h2d_puts += 1
+                batch = jnp.stack(rows)
+                ops.eager_ops += _stack_programs(tile.b_tile)
             n_valid = None
             if tile.needs_mask and seg[0] in ("layers", "tdm"):
                 n_valid = np.fromiter(
@@ -880,7 +951,7 @@ class VisionEngine:
                 # every member past its first soft TDM carries a package
                 # mass; batch-pad rows get 0 (their packages are don't-care)
                 pkg_mass = jnp.stack(
-                    [jnp.asarray(st.pkg_mass, jnp.float32).reshape(())
+                    [st.pkg_mass.take(None, ops).reshape(())
                      for st in states]
                     + [jnp.zeros((), jnp.float32)]
                     * (tile.b_tile - len(states)))
@@ -888,7 +959,7 @@ class VisionEngine:
                                   + _stack_programs(tile.b_tile))
                 ops.h2d_puts += 1
             tile_runs.append((tile, member_slots, seg, k, soft, prec, batch,
-                              n_valid, pkg_mass))
+                              n_valid, pkg_mass, passed))
 
         lane_runs = []
         for lane in plan.lanes:
@@ -901,13 +972,16 @@ class VisionEngine:
             steps = tuple(steps)
             seed = None
             if st.pkg_mass is not None:
-                seed = jnp.asarray(st.pkg_mass, jnp.float32).reshape(1)
+                seed = st.pkg_mass.take(None, ops).reshape(1)
                 ops.eager_ops += 1
-            lane_runs.append((slot, steps, jnp.asarray(st.x,
-                                                       jnp.float32)[None],
-                              seed))
-            ops.eager_ops += 1
-            ops.h2d_puts += isinstance(st.x, np.ndarray)
+            if isinstance(st.x, np.ndarray):
+                # a lane from admission: its patches, one put
+                x1 = jnp.asarray(st.x[None])
+                ops.h2d_puts += 1
+            else:
+                x1 = st.x.take(st.n_tokens, ops)[None]
+                ops.eager_ops += 1
+            lane_runs.append((slot, steps, x1, seed))
         if tr.enabled:
             tr.end("stage", track="engine")
 
@@ -916,7 +990,9 @@ class VisionEngine:
 
         def run_tile(run):
             (tile, member_slots, seg, k, soft, prec, batch, n_valid,
-             pkg_mass) = run
+             pkg_mass, passed) = run
+            if not passed:
+                self._stacked.add(tile.bucket_key)
             self.precision_dispatches[prec] += 1
             if prec == "int8":
                 self.dequant_dispatches += 1
@@ -936,28 +1012,20 @@ class VisionEngine:
                 tr.end("tile", track="pipeline")
                 tr.begin("unstage", track="pipeline")
             kind = seg[0]
-            if kind != "head":
-                # a row slice per member (and one of its package mass)
-                m = len(member_slots)
-                ops.eager_ops += 2 * m
-                ops.h2d_puts += y.ndim * m
-                if soft:
-                    ops.eager_ops += 2 * m
-                    ops.h2d_puts += mass.ndim * m
+            # members keep references to their rows of the output; the
+            # next staging pass slices them only where it must
             for b, slot in enumerate(member_slots):
                 st = self._live[slot]
+                if kind == "head":
+                    produced.append((st, y, b, "tile"))
+                else:
+                    st.x = _Rows(y, b)
                 if kind == "embed":
                     st.n_tokens += 1          # + CLS
-                    st.x = y[b, : st.n_tokens]
-                elif kind == "layers":
-                    st.x = y[b, : st.n_tokens]
                 elif kind == "tdm":
                     st.n_tokens = k + 2       # CLS + k kept + fused/package
-                    st.x = y[b, : st.n_tokens]
                     if soft:
-                        st.pkg_mass = mass[b]
-                else:  # head
-                    produced.append((st, y, b, "tile"))
+                        st.pkg_mass = _Rows(mass, b)
                 st.seg_idx += 1
             if tr.enabled:
                 tr.end("unstage", track="pipeline")
@@ -993,14 +1061,16 @@ class VisionEngine:
             return handles
 
         def complete(handles):
+            # one copy to the host per head or lane output, rows taken
+            # there
+            host: Dict[int, np.ndarray] = {}
             for st, y, row, path in produced:
                 req = st.req
-                req.logits = np.asarray(y[row])
+                if id(y) not in host:
+                    host[id(y)] = np.asarray(y)
+                req.logits = host[id(y)][row]
                 req.done = True
                 out[req.uid] = req.logits
-                # the row slice, and the copy to the host
-                ops.eager_ops += 2
-                ops.h2d_puts += y.ndim
                 ops.to_host += 1
                 if tr.enabled:
                     self._record_request(st, path, time.perf_counter())
@@ -1008,6 +1078,7 @@ class VisionEngine:
             if tr.enabled:
                 tr.record("step", t_step, time.perf_counter(), track="engine",
                           step=step_no, tiles=len(plan.tiles),
+                          passthrough_tiles=ops.passthrough_tiles,
                           lanes=len(plan.lanes), eager_ops=ops.eager_ops,
                           h2d_puts=ops.h2d_puts, to_host=ops.to_host,
                           compiled=(self.segments.jit_compile_count()

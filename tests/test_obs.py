@@ -519,7 +519,8 @@ def test_request_records_share_uid_and_order(packed_vit):
 
 def test_eager_ops_match_a_hand_count(packed_vit):
     """Three requests of one size and keep rate at max_batch 4 (planner
-    off): every step runs one 3-member tile padded with a zero row."""
+    off): every step runs one 3-member tile padded with a zero row, so no
+    tile passes the previous output through."""
     cfg, masked, packed = packed_vit
     tr = Tracer()
     eng = VisionEngine(cfg, masked, packed, VisionEngineConfig(
@@ -530,24 +531,26 @@ def test_eager_ops_match_a_hand_count(packed_vit):
     steps = [s["attrs"] for s in tr.span_log if s["name"] == "step"]
     kinds = [seg[0] for seg in eng.segments.plan]
     assert [a["step"] for a in steps] == list(range(len(kinds)))
-    # per step: a pad per member (3), a zero row (convert + broadcast: 2),
-    # the stack of 4 rows (4 expand_dims + 1 concatenate), and a row
-    # slice per member (dynamic_slice + squeeze: 6), unstaged after the
-    # segment or, after the head, copied to the host
-    assert [a["eager_ops"] for a in steps] == [3 + 2 + 5 + 6] * len(kinds)
-    # puts: the patches at the embed step (3), the pads' fill values (3),
-    # the zero row's (1), and a start index per axis of each row slice
-    # (3-d activations, 2-d logits)
-    puts = {"embed": 3 + 3 + 1 + 3 * 3, "layers": 3 + 1 + 3 * 3,
-            "tdm": 3 + 1 + 3 * 3, "head": 3 + 1 + 3 * 2}
-    assert [a["h2d_puts"] for a in steps] == [puts[k] for k in kinds]
+    # the embed step stacks the patches on the host: no program. Every
+    # later step slices each member's rows out of the previous output
+    # (dynamic_slice + squeeze: 6), pads each member (3), adds a zero row
+    # (convert + broadcast: 2) and stacks 4 rows (4 expand_dims + 1
+    # concatenate); the head's logits reach the host as one copy
+    assert [a["eager_ops"] for a in steps] == [
+        0 if k == "embed" else 6 + 3 + 2 + 5 for k in kinds]
+    # puts: the stacked patches at the embed step (1); later, a start
+    # index per axis of each 3-d row slice (9), the pads' fill values (3)
+    # and the zero row's (1)
+    assert [a["h2d_puts"] for a in steps] == [
+        1 if k == "embed" else 3 * 3 + 3 + 1 for k in kinds]
     assert [a["to_host"] for a in steps] == [3 if k == "head" else 0
                                              for k in kinds]
+    assert [a["passthrough_tiles"] for a in steps] == [0] * len(kinds)
     assert all(a["tiles"] == 1 and a["lanes"] == 0 for a in steps)
     # a fresh engine compiles each segment at its first step, and the
     # same requests served again compile nothing
     assert [a["compiled"] for a in steps] == [1] * len(kinds)
-    for key in ("eager_ops", "h2d_puts", "to_host"):
+    for key in ("eager_ops", "h2d_puts", "to_host", "passthrough_tiles"):
         assert after[key] - before[key] == sum(a[key] for a in steps)
     n = len(tr.span_log)
     eng.serve(_requests(cfg, [(16, 0.5, 0)] * 3))
@@ -561,7 +564,8 @@ def test_engine_counts_device_ops_with_tracing_off(packed_vit):
     eng.serve(_requests(cfg, [(16, 0.5, 0)] * 3))
     n = len(eng.segments.plan)
     st = eng.stats()
-    assert st["eager_ops"] == 16 * n and st["to_host"] == 3
+    assert st["eager_ops"] == 16 * (n - 1) and st["to_host"] == 3
+    assert st["passthrough_tiles"] == 0
 
 
 def test_tracing_keeps_logits_bitexact(packed_vit):

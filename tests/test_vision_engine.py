@@ -15,6 +15,7 @@ from repro.configs import DEIT_SMALL
 from repro.core import packed_runner as PR
 from repro.models import model as M
 from repro.models import pruning_glue as PG
+from repro.obs import Tracer
 from repro.serving import (Request, ServeEngine, EngineConfig,
                            VisionEngine, VisionEngineConfig, VisionRequest)
 
@@ -92,6 +93,113 @@ def test_batch_composition_invariance(packed_vit):
                          VisionEngineConfig(max_batch=4))
     out_crowd = crowd.serve(crowd_reqs)
     assert np.array_equal(out_solo[0], out_crowd[7])
+
+
+def _serve(eng, tr, cfg, mixes):
+    """Serve ``mixes`` through a traced engine; returns the requests,
+    their logits and the attrs of the ``step`` records of this serve."""
+    mark = len(tr.span_log)
+    reqs = _mixed_requests(cfg, mixes)
+    out = eng.serve(reqs)
+    steps = [s["attrs"] for s in tr.span_log[mark:] if s["name"] == "step"]
+    return reqs, out, steps
+
+
+def _stacked(segments, reqs, b_tile):
+    """Logits of a cohort of requests of one size and keep rate, every
+    tile built the per-member way: each member's rows sliced out of the
+    last output, zero rows up to ``b_tile``, stacked on the device. (On
+    the CPU a batched tile need not match the one-row offline path bit
+    for bit, so this is the reference for batched tiles.)"""
+    import jax.numpy as jnp
+    n, r_t = reqs[0].n_patches, reqs[0].r_t
+    xs = [jnp.asarray(r.patches) for r in reqs]
+    for seg in segments.plan:
+        k = PR.tdm_keep_count(n, r_t) if seg[0] == "tdm" else None
+        rows = xs + [jnp.zeros_like(xs[0])] * (b_tile - len(xs))
+        y = segments.run(seg, jnp.stack(rows), k=k)
+        n = n + 1 if seg[0] == "embed" else k + 2 if k is not None else n
+        xs = [y[b] if seg[0] == "head" else y[b, :n]
+              for b in range(len(reqs))]
+    return [np.asarray(x) for x in xs]
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_lockstep_cohort_passes_tiles_through(packed_vit, depth):
+    """A cohort of four requests of one size and keep rate at max_batch
+    4, served twice. The first time every tile is stacked (a bucket's
+    first tile always is, so its eager programs compile with it). The
+    second time every tile after the embed step is the previous tile's
+    output whole, staged with no device program, and the head's logits
+    come back in one copy; at depth 2 the next step consumes (donates)
+    an output of the step still in flight. Both give the same bits."""
+    cfg, masked, packed = packed_vit
+    tr = Tracer()
+    eng = VisionEngine(cfg, masked, packed, VisionEngineConfig(
+        max_batch=4, pipeline_depth=depth), tracer=tr)
+    mixes = [(16, 0.5, 0)] * 4
+    reqs, first, steps = _serve(eng, tr, cfg, mixes)
+    kinds = [seg[0] for seg in eng.segments.plan]
+    # after the host-stacked embed step: 4 row slices (8), 4 pads, a
+    # stack of 4 (5)
+    assert [(a["passthrough_tiles"], a["eager_ops"]) for a in steps] == [
+        (0, 0 if k == "embed" else 8 + 4 + 5) for k in kinds]
+    _, out, steps = _serve(eng, tr, cfg, mixes)
+    st = eng.stats()
+    assert st["jit_compile_count"] <= st["bucket_count"]
+    assert [a["tiles"] for a in steps] == [1] * len(kinds)
+    assert [a["passthrough_tiles"] for a in steps] == [
+        0 if k == "embed" else 1 for k in kinds]
+    assert [a["eager_ops"] for a in steps] == [0] * len(kinds)
+    # the patches, stacked on the host, are the one put
+    assert [a["h2d_puts"] for a in steps] == [
+        1 if k == "embed" else 0 for k in kinds]
+    assert st["passthrough_tiles"] == len(kinds) - 1
+    ref = _stacked(eng.segments, reqs, 4)
+    for r, want in zip(reqs, ref):
+        assert np.array_equal(want, first[r.uid]), r.uid
+        assert np.array_equal(want, out[r.uid]), r.uid
+
+
+# requests, max_batch, and per step of a second serve (tiles,
+# passthrough_tiles, eager_ops)
+FALLBACK = {
+    # three members padded to four rows: every tile is stacked. Past the
+    # embed step each costs the hand count of the per-member path, 16
+    # programs: 3 row slices (6), 3 pads, a zero row (2), a stack of 4 (5)
+    "padded": ([(16, 0.5, 0)] * 3, 4, [(1, 0, 0)] + [(1, 0, 16)] * 4),
+    # 16 and 9 patches both keep 8 tokens at the TDM: singleton tiles
+    # pass through until the second layers tile, which holds rows of two
+    # outputs and is stacked at the same hand count, 9 programs: 2 row
+    # slices (4), 2 pads, a stack of 2 (3)
+    "ragged": ([(16, 0.5, 0), (9, 0.8, 0)], 2,
+               [(2, 0, 0), (2, 2, 0), (2, 2, 0), (1, 0, 9), (1, 1, 0)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FALLBACK))
+def test_tiles_that_are_no_whole_output_keep_the_stacking_path(
+        packed_vit, case):
+    """A tile with a zero row, or with rows of two outputs, is sliced,
+    padded and stacked as before: same programs, and logits bit for bit
+    those of the first serve, in which every bucket is new and so every
+    tile is stacked."""
+    cfg, masked, packed = packed_vit
+    mixes, max_batch, want = FALLBACK[case]
+    tr = Tracer()
+    eng = VisionEngine(cfg, masked, packed,
+                       VisionEngineConfig(max_batch=max_batch), tracer=tr)
+    assert [seg[0] for seg in eng.segments.plan] == [
+        "embed", "layers", "tdm", "layers", "head"]
+    reqs, ref, steps = _serve(eng, tr, cfg, mixes)
+    assert sum(a["passthrough_tiles"] for a in steps) == 0
+    reqs, out, steps = _serve(eng, tr, cfg, mixes)
+    assert [(a["tiles"], a["passthrough_tiles"], a["eager_ops"])
+            for a in steps] == want
+    st = eng.stats()
+    assert st["jit_compile_count"] <= st["bucket_count"]
+    for r in reqs:
+        assert np.array_equal(ref[r.uid], out[r.uid]), r.uid
 
 
 def test_padded_modes_serve_everyone_close(packed_vit):
