@@ -6,12 +6,14 @@
 Builds DeiT-Small at its published widths (12 layers, d_model 384, 6 heads,
 224 px, TDM at layers 2/6/9, r_b 0.5) from a seed, prunes and packs it,
 serves a mixed stream of 8 requests (49/169/196 patches, keep rates
-0.5/0.7/default) through ``VisionEngine`` with the SBMM kernel compiled, and
-compares every request's logits with the masked-dense reference run at
-``highest`` matmul precision. A request that misses the tolerance passes
-only if a TDM top-k near-tie made the served path keep other tokens and the
-logits agree with a reference that keeps those tokens. Runs in one process
-and starts no other.
+0.5/0.7/default) through ``VisionEngine`` with kernels compiled, checks
+that the fp32 encoder segment runs its projections as XLA matmuls and the
+int8 one through the compiled SBMM kernel, and compares every request's
+logits with the masked-dense reference run at ``highest`` matmul
+precision. A request that misses the tolerance passes only if a TDM top-k
+near-tie made the served path keep other tokens and the logits agree with
+a reference that keeps those tokens. Runs in one process and starts no
+other.
 
 Exits non-zero, printing no result line, when JAX finds no TPU or any check
 fails. On success the last line of standard output is the JSON result.
@@ -152,9 +154,10 @@ def _explain_by_flips(engine, masked, r, r_t, served):
     return "FAIL", detail
 
 
-def _kernel_in_segment(engine) -> bool:
+def _kernels_in_segment(engine) -> bool:
     """Lower and compile one served encoder segment at B=MAX_BATCH, N=197
-    and look for the compiled Pallas kernel in both texts."""
+    per weight tier and look for the compiled Pallas kernel: the fp32
+    projections are XLA matmuls (none), the int8 ones SBMM (one)."""
     import jax.numpy as jnp
 
     segs = engine.segments
@@ -162,13 +165,17 @@ def _kernel_in_segment(engine) -> bool:
     n = (engine.cfg.image_size // engine.cfg.patch_size) ** 2 + 1
     x = jnp.zeros((MAX_BATCH, n, engine.cfg.d_model), jnp.float32)
     nv = jnp.full((MAX_BATCH,), n, jnp.int32)
-    lowered = segs._layers.lower(segs.params, segs.packed_for("fp32"), x, nv,
-                                 lo=seg[1], hi=seg[2], prec="fp32")
-    in_lowered = "tpu_custom_call" in lowered.as_text()
-    in_compiled = "tpu_custom_call" in lowered.compile().as_text()
-    print(f"segment {seg} at B={MAX_BATCH} N={n}: tpu_custom_call in "
-          f"lowered={in_lowered} compiled={in_compiled}")
-    return in_lowered and in_compiled
+    ok = True
+    for tier, want in (("fp32", False), ("int8", True)):
+        lowered = segs._layers.lower(segs.params, segs.packed_for(tier), x,
+                                     nv, lo=seg[1], hi=seg[2], prec=tier)
+        in_lowered = "tpu_custom_call" in lowered.as_text()
+        in_compiled = "tpu_custom_call" in lowered.compile().as_text()
+        print(f"{tier} segment {seg} at B={MAX_BATCH} N={n}: "
+              f"tpu_custom_call in lowered={in_lowered} "
+              f"compiled={in_compiled} (expected {want})")
+        ok &= in_lowered == want and in_compiled == want
+    return ok
 
 
 def smoke(cfg) -> bool:
@@ -213,8 +220,8 @@ def smoke(cfg) -> bool:
         print("FAIL: the warm pass did not reproduce the first pass")
         ok = False
 
-    if not _kernel_in_segment(engine):
-        print("FAIL: the served segment holds no compiled Pallas kernel")
+    if not _kernels_in_segment(engine):
+        print("FAIL: a served segment's Pallas kernels are not the tier's")
         ok = False
 
     masked = engine.segments.params

@@ -3,9 +3,13 @@
 After simultaneous pruning, ``pack_model`` hardens the masks and converts
 every block-pruned attention weight into the block-compressed SBMM format
 (load-balanced column order included). ``forward_vit_packed`` then runs the
-ViT with those weights executed THROUGH the SBMM kernel — the software
-twin of the MPCA executing the pruned model, validated end-to-end against
-the masked-dense forward (tests/test_packed_runner.py).
+ViT from those weights, validated end-to-end against the masked-dense
+forward (tests/test_packed_runner.py). The fp32 tier multiplies by each
+projection's block-masked dense matrix, rebuilt once from its packed
+blocks: at b = 16 and r_b = 0.5 no 128x128 MXU tile of the weight is
+empty, so a TPU-shaped SBMM can at best match that dense matmul. The
+quantized tiers (``repro.core.quant``) keep the packed format and run the
+SBMM kernels, which stream their int8/fp16 blocks.
 
 MLP column/row-pruned weights stay dense-masked (the paper maps them to
 DBMM — a dense matmul over the shrunken width — which XLA already emits).
@@ -170,10 +174,14 @@ def token_trajectory(cfg: ModelConfig, n_patches: int,
 # ===========================================================================
 def _proj(params: Dict, packed: Dict, i: int, name: str, inp: jax.Array
           ) -> jax.Array:
-    key = f"layers/{i}/attn/{name}"
-    if key in packed:
-        return sbmm(inp, packed[key])
-    return L.linear(inp, params["layers"][i]["attn"][name])
+    """One attention projection by the weight ``packed`` holds for it: a
+    block-compressed weight runs the SBMM kernel, a dense (block-masked)
+    matrix an XLA matmul; an unpruned weight comes from ``params``."""
+    w = packed.get(f"layers/{i}/attn/{name}")
+    if isinstance(w, (packing.PackedWeight, Q.QuantizedPackedWeight)):
+        return sbmm(inp, w)
+    return L.linear(inp, params["layers"][i]["attn"][name] if w is None
+                    else w)
 
 
 def _encoder_attn(cfg: ModelConfig, params: Dict, packed: Dict,
@@ -182,7 +190,8 @@ def _encoder_attn(cfg: ModelConfig, params: Dict, packed: Dict,
                   precision: str = "fp32"
                   ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """Attention sublayer + residual of encoder layer ``i`` (projections
-    through SBMM when packed). ``n_valid`` masks token padding out of the
+    by ``_proj``: block-masked dense matmuls at fp32, SBMM for quantized
+    packed weights). ``n_valid`` masks token padding out of the
     attention and of the TDM scoring; padded rows' scores are exactly 0.
 
     ``precision`` is the quantized-serving knob: weight precision is
@@ -377,12 +386,14 @@ def forward_vit_packed(cfg: ModelConfig, params: Dict,
                        schedule: Optional[Sequence[float]] = None,
                        soft: bool = False,
                        precision: str = "fp32") -> M.Output:
-    """ViT forward with attention projections executed via the SBMM kernel
-    (interpret mode on CPU; native Pallas on TPU backends).
+    """ViT forward with the attention projections taken from ``packed``
+    through the segment executor's weight set at ``precision``: at fp32
+    XLA matmuls by the block-masked dense weights, at int8/fp16 the SBMM
+    kernels (interpret mode on CPU; native Pallas on TPU backends).
 
     ``params`` should be the MASKED tree (``PG.apply_pruning``) so the
-    MLPs run masked-dense (the paper's DBMM path); the SBMM-packed
-    attention weights carry their masks structurally.
+    MLPs run masked-dense (the paper's DBMM path); the packed attention
+    weights carry their masks structurally.
 
     This is the single-request oracle the vision serving engine is
     bit-exact against: it walks the same ``vit_segments`` plan through the
@@ -473,12 +484,15 @@ class PackedVitSegments:
                 f"quant_granularity must be one of {Q.GRANULARITIES}, "
                 f"got {quant_granularity!r}")
         self.quant_granularity = quant_granularity
-        # Quantized packed dicts are derived lazily on first use — an
-        # fp32-only engine never pays the quantization pass, and precisions
-        # share the one params tree (embed/MLP/head weights are
-        # precision-independent: only the SBMM-packed attention weights
-        # re-quantize).
-        self._packed_by: Dict[str, Dict] = {"fp32": packed}
+        # The fp32 weight set is every packed projection as its
+        # block-masked dense [K, N] matrix in logical column order, built
+        # on the host and put on the device once. Quantized packed dicts
+        # are derived from ``packed`` lazily on first use — an fp32-only
+        # engine never pays the quantization pass, and precisions share the
+        # one params tree (embed/MLP/head weights are precision-independent:
+        # only the packed attention weights re-quantize).
+        self._packed_by: Dict[str, Dict] = {
+            "fp32": {path: pw.to_dense() for path, pw in packed.items()}}
         # Only the "layers" segment preserves the activation shape
         # [B, n, D] input->output, so only its input tile is donatable
         # (embed/tdm/head change shapes — donating them would just warn
@@ -525,10 +539,11 @@ class PackedVitSegments:
         self._fused_trajectories: set = set()
 
     def packed_for(self, precision: str) -> Dict:
-        """The packed dict at ``precision`` — quantized lazily on first use
-        (``fp32`` is the original dict; ``fp16``/``int8`` derive from it
+        """The attention weight set at ``precision``: ``fp32`` is the
+        block-masked dense matrices built at construction; ``fp16``/
+        ``int8`` are quantized lazily on first use from the packed dict
         via :func:`repro.core.quant.quantize_packed_dict` at this runner's
-        ``quant_granularity``) and memoized so every tile/lane at a given
+        ``quant_granularity``, and memoized so every tile/lane at a given
         precision shares one set of device buffers."""
         if precision not in Q.PRECISIONS:
             raise ValueError(

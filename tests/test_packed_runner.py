@@ -1,8 +1,11 @@
-"""End-to-end packed-model execution: the SBMM-kernel ViT must match the
-masked-dense oracle — the accelerator-vs-software parity check."""
+"""End-to-end packed-model execution: the packed ViT must match the
+masked-dense oracle — the accelerator-vs-software parity check. Its fp32
+projections are XLA matmuls by the block-masked weights; the quantized
+tiers run them through the SBMM kernels."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import DEIT_SMALL
 from repro.core import packed_runner as PR
@@ -20,20 +23,57 @@ def test_packed_vit_matches_masked_dense(rng_key):
     n = (cfg.image_size // cfg.patch_size) ** 2
     patches = jax.random.normal(rng_key, (2, n, cfg.patch_size ** 2 * 3))
 
-    # the deployment path runs MLPs masked-dense (DBMM analog) and
-    # attention through SBMM; pass the masked tree for the dense parts
+    # the deployment path runs MLPs masked-dense (DBMM analog) and, at
+    # fp32, the attention projections by the block-masked dense weights
+    # rebuilt from the packed blocks; pass the masked tree for the MLPs
     masked = PG.apply_pruning(cfg, params, scores)
-    out_kernel = PR.forward_vit_packed(cfg, masked, packed, patches,
+    out_packed = PR.forward_vit_packed(cfg, masked, packed, patches,
                                        use_tdm=False)
     out_oracle = PR.masked_dense_reference(cfg, params, scores, patches,
                                            use_tdm=False)
-    np.testing.assert_allclose(np.asarray(out_kernel.logits),
+    np.testing.assert_allclose(np.asarray(out_packed.logits),
                                np.asarray(out_oracle.logits),
                                atol=2e-3, rtol=2e-3)
 
 
+@pytest.mark.parametrize("tier", ["fp32", "int8"])
+def test_sbmm_kernel_only_in_quantized_tier_programs(rng_key, tier):
+    """The served programs that hold attention projections (tiles of
+    encoder layers, TDM layers, and express lanes) call no Pallas kernel at
+    fp32, where the projections are XLA matmuls by the block-masked
+    weights, and the SBMM kernel at int8."""
+    cfg = DEIT_SMALL.reduced()
+    params = M.init_params(cfg, rng_key)
+    scores = PG.init_scores(cfg, params, jax.random.fold_in(rng_key, 7))
+    masked = PG.apply_pruning(cfg, params, scores)
+    seg = PR.PackedVitSegments(cfg, masked, PR.pack_model(cfg, params,
+                                                          scores))
+    pk = seg.packed_for(tier)
+    n = (cfg.image_size // cfg.patch_size) ** 2
+    x = jnp.zeros((2, n + 1, cfg.d_model), jnp.float32)
+    layers = next(s for s in seg.plan if s[0] == "layers")
+    tdm = next(s for s in seg.plan if s[0] == "tdm")
+    lane = tuple((s, PR.tdm_keep_count(n + 1, 0.5) if s[0] == "tdm"
+                  else None) for s in seg.plan)
+    traced = {
+        "vit_layers": seg._layers.trace(masked, pk, x, None, lo=layers[1],
+                                        hi=layers[2], prec=tier),
+        "vit_tdm": seg._tdm.trace(masked, pk, x, None, layer=tdm[1], k=2,
+                                  prec=tier),
+        "vit_lane": seg._fused.trace(
+            masked, pk, jnp.zeros((1, n, cfg.patch_size ** 2 * 3)), None,
+            steps=lane, prec=tier),
+    }
+    for name, tr in traced.items():
+        n_kernels = str(tr.jaxpr).count("pallas_call")
+        if tier == "fp32":
+            assert n_kernels == 0, name
+        else:
+            assert n_kernels >= 1, name
+
+
 def test_packed_vit_with_tdm_runs(rng_key):
-    """Both prunings simultaneously active on the kernel execution path —
+    """Both prunings simultaneously active on the packed execution path —
     the full deployment configuration of the paper."""
     cfg = DEIT_SMALL.reduced()
     params = M.init_params(cfg, rng_key)

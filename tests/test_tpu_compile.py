@@ -94,9 +94,12 @@ def test_sbmm_compiles_for_v5e(compiled_kernels, precision, granularity):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_packed_encoder_segment_compiles_for_v5e(compiled_kernels):
+@pytest.mark.parametrize("tier", ["fp32", "int8"])
+def test_packed_encoder_segment_compiles_for_v5e(compiled_kernels, tier):
     """A jitted PackedVitSegments encoder segment of full-width DeiT-Small,
-    at the engine's largest tile, holds the compiled SBMM kernel."""
+    at the engine's largest tile, compiles for v5e: at fp32 its attention
+    projections are XLA dots by the block-masked weights, with no Pallas
+    kernel; at int8 it holds the compiled SBMM kernel."""
     key = jax.random.PRNGKey(0)
     params = M.init_params(CFG, key)
     scores = PG.init_scores(CFG, params, jax.random.fold_in(key, 7))
@@ -109,7 +112,11 @@ def test_packed_encoder_segment_compiles_for_v5e(compiled_kernels):
     nv = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=compiled_kernels)
     seg = next(s for s in segs.plan if s[0] == "layers")
     compiled = segs._layers.lower(spec(segs.params),
-                                  spec(segs.packed_for("fp32")), x, nv,
+                                  spec(segs.packed_for(tier)), x, nv,
                                   lo=seg[1], hi=seg[2],
-                                  prec="fp32").compile()
-    assert "tpu_custom_call" in compiled.as_text()
+                                  prec=tier).compile().as_text()
+    if tier == "fp32":
+        assert "tpu_custom_call" not in compiled
+        assert "convolution(" in compiled
+    else:
+        assert "tpu_custom_call" in compiled
