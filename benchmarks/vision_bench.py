@@ -156,9 +156,11 @@ def run_mode(cfg, masked, packed, cost_model, reqs_factory, *, slots: int,
     st = engine.stats()
     real = (st["batcher_real_cells"] - warm["batcher_real_cells"]
             + st["plan_lane_cells"] - warm["plan_lane_cells"])
-    # device-busy proxy: at depth 1 the host dispatches then immediately
-    # blocks, so dispatch + block wall time brackets the device's work;
-    # wall_vs_device > 1 is host overhead the pipeline can hide
+    # device-busy proxy: at depth 1 the host dispatches then blocks, so
+    # dispatch + block wall time brackets the device's work (less what
+    # unwaited steps, which read nothing on the host, ran while the host
+    # staged the next step); wall_vs_device > 1 is host overhead the
+    # pipeline can hide
     busy = (st["pipeline_block_s"] - warm["pipeline_block_s"]
             + st["pipeline_dispatch_s"] - warm["pipeline_dispatch_s"])
     if registry is not None:

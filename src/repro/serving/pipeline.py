@@ -18,20 +18,37 @@ step is split into three phases and only the last one ever waits.
               chains) advance now, because they are deterministic given
               the plan — the enabler for computing plan N+1 while the
               device still executes plan N.
-    complete  (pipeline) block on step N's output handles and materialize
-              host-visible results (logits, generated tokens).
+    complete  (pipeline) block on the outputs step N's ``complete``
+              callback reads on the host (logits, generated tokens), then
+              run the callback.
+
+What ``complete`` waits for: only the handles ``dispatch`` returns, which
+are the outputs the host reads. Outputs that stay on the device for a
+later step (``StagedStep.outputs``: a vision tile's activations, which the
+next step takes whole) are not waited on, because the device orders the
+consuming step after them anyway. A step with no host-read output
+completes without waiting: its callback still runs (counters, records),
+and the host goes on to stage the next step while the device works. A
+later step that is waited on (a head's logits) waits for the whole chain
+before it.
+
+Run-ahead bound: after ``max_unwaited`` unwaited steps in a row, the next
+step with nothing to read waits on its device outputs, so the host never
+queues unread steps without limit. The vision engine sets the bound to
+the length of its model's segment plan, so a request's whole trajectory
+fits; the default 0 waits on every step.
 
 ``depth`` bounds how many dispatched-but-incomplete steps may be in
-flight. Depth 1 completes each step inside :meth:`submit` — bit-exact,
-step-for-step identical to the old synchronous loops. Depth 2
+flight. Depth 1 completes each step inside :meth:`submit`: a step that
+carries logits has them on the host when ``submit`` returns. Depth 2
 double-buffers: while the device executes step N, the host stages step
 N+1, and step N is completed only when N+1's dispatch has been enqueued.
 Results are bit-exact at any depth — the pipeline reorders *waiting*, not
 math: every step's inputs are fully determined at its stage time.
 
-This module owns the engines' ONLY ``jax.block_until_ready`` call site
-(CI greps for strays); everything upstream must hand the pipeline handles
-instead of blocking.
+This module still owns the engines' ONLY ``jax.block_until_ready`` call
+site (CI greps for strays); everything upstream must hand the pipeline
+handles instead of blocking.
 """
 from __future__ import annotations
 
@@ -84,9 +101,13 @@ class StepReport:
 class StagedStep:
     """One fully-staged engine step awaiting dispatch.
 
-    ``dispatch`` enqueues the device work and returns the output handles
-    to block on; ``complete`` runs after the block and materializes
-    host-visible results; ``rollback`` (optional) undoes any host-mirror
+    ``dispatch`` enqueues the device work and returns the handles of the
+    outputs the host reads (empty when it reads none), which completion
+    blocks on; ``outputs`` (optional) holds the step's other device
+    outputs, which a later step consumes on the device and completion
+    waits on only at the run-ahead bound; ``complete`` runs after any
+    block and materializes host-visible results (``waited`` says whether
+    the pipeline blocked first); ``rollback`` (optional) undoes any host-mirror
     mutations staging made, so the step can be dropped pre-dispatch when a
     replan invalidates it (mid-step submission). Once dispatched, a step
     can no longer be dropped — device work is in flight."""
@@ -96,13 +117,17 @@ class StagedStep:
     rollback: Optional[Callable[[], None]] = None
     label: str = ""
     handles: Any = None
+    outputs: Any = None
     dispatched: bool = False
     completed: bool = False
+    waited: bool = False
     modeled_ms: float = 0.0   # the cost model's price of this step (vision
     # engines set it from the staged ExecutionPlan; 0 = unmodeled). Paired
     # with the measured dispatch+block wall time at completion, this is
     # the per-step modeled-vs-measured sample behind the calibration-drift
-    # metric (pipeline stats: modeled_ms_total / measured_ms_total).
+    # metric (pipeline stats: modeled_ms_total / measured_ms_total); an
+    # unwaited step blocks 0 s, and a later waited step's block covers
+    # what of its device work the host did not overlap.
     dispatch_wall_s: float = 0.0  # wall seconds this step's dispatch took
     # (pipeline-recorded; the complete phase adds its block time to form
     # the measured cost)
@@ -113,15 +138,25 @@ class StepPipeline:
 
     ``depth`` = max steps dispatched but not yet completed. ``submit``
     dispatches the new step, then completes the oldest in-flight steps
-    until at most ``depth - 1`` remain — so depth 1 is the synchronous
-    path and depth 2 keeps exactly one step on the device while the host
-    stages the next.
+    until at most ``depth - 1`` remain — so depth 1 completes every step
+    inside ``submit`` and depth 2 keeps exactly one step on the device
+    while the host stages the next.
+
+    Completing a step blocks only on its host-read handles; a step with
+    none goes unwaited unless ``max_unwaited`` steps in a row already
+    did, and then waits on its device ``outputs`` (module docstring).
     """
 
-    def __init__(self, depth: int = 1, tracer: Optional[Tracer] = None):
+    def __init__(self, depth: int = 1, tracer: Optional[Tracer] = None,
+                 max_unwaited: int = 0):
         if depth < 1:
             raise ValueError(f"pipeline depth must be >= 1, got {depth}")
+        if max_unwaited < 0:
+            raise ValueError(f"max_unwaited must be >= 0, got "
+                             f"{max_unwaited}")
         self.depth = depth
+        self.max_unwaited = max_unwaited
+        self._unwaited_run = 0   # unwaited completions since the last wait
         # wall-clock span tracer (repro.obs): dispatch/complete spans on
         # the "pipeline" track, complete split into block (waiting for the
         # device) and to_host (the step's complete callback). Disabled by
@@ -132,9 +167,12 @@ class StepPipeline:
         # accounting (the bench's wall_vs_device column reads these)
         self.steps = 0           # steps dispatched
         self.drops = 0           # staged steps dropped pre-dispatch
-        self.overlap_hits = 0    # completions whose handles were already
-        #                          ready — the device finished while the
-        #                          host was staging (overlap realized)
+        self.overlap_hits = 0    # waited completions whose handles were
+        #                          already ready — the device finished
+        #                          while the host was staging (overlap
+        #                          realized)
+        self.steps_unwaited = 0  # completions that blocked on nothing:
+        #                          the host read no output of the step
         self.block_s = 0.0       # wall seconds inside block_until_ready
         self.dispatch_s = 0.0    # wall seconds enqueueing device work
         self.modeled_ms_total = 0.0   # sum of completed steps' cost-model
@@ -158,9 +196,10 @@ class StepPipeline:
         in-flight steps."""
         tr = self.tracer
         t0 = time.perf_counter()
-        if not self._inflight:
+        if not self._inflight and self._unwaited_run == 0:
             # the device queue was empty for the whole host-side gap since
-            # it last drained — that gap is device starvation
+            # it last drained — that gap is device starvation (after an
+            # unwaited completion the device may still be busy with it)
             self.starved_s += t0 - self._idle_since
         if tr.enabled:
             tr.begin("dispatch", track="pipeline", label=step.label)
@@ -188,30 +227,42 @@ class StepPipeline:
     def flush(self) -> None:
         """Complete every in-flight step (end of serve, or before an
         operation that must observe fully-materialized state, e.g. an
-        elastic rebuild)."""
+        elastic rebuild). As in :meth:`submit`, a step with no host-read
+        output completes without waiting for the device."""
         while self._inflight:
             self._complete_oldest()
 
     def _complete_oldest(self) -> None:
         step = self._inflight.popleft()
         tr = self.tracer
-        # a later step may already have consumed (donated) an output of
-        # this one; the device orders that work before the later step's,
-        # so the rest of the outputs say when this step is done
-        leaves = [l for l in jax.tree_util.tree_leaves(step.handles)
-                  if not (hasattr(l, "is_deleted") and l.is_deleted())]
-        if leaves and all(l.is_ready() for l in leaves
-                          if hasattr(l, "is_ready")):
+        # block on what the host reads; at the run-ahead bound, on the
+        # step's device outputs, or, where later in-flight steps already
+        # consumed them, on theirs (the device runs those after this one)
+        leaves = _live_leaves(step.handles)
+        if not leaves and self._unwaited_run >= self.max_unwaited:
+            leaves = (_live_leaves(step.outputs)
+                      or _live_leaves([s.outputs for s in self._inflight]))
+        step.waited = bool(leaves)
+        if step.waited and all(l.is_ready() for l in leaves
+                               if hasattr(l, "is_ready")):
             self.overlap_hits += 1
         if tr.enabled:
             tr.begin("complete", track="pipeline", label=step.label)
-            tr.begin("block", track="pipeline")
-        t0 = time.perf_counter()
-        jax.block_until_ready(leaves)
-        block = time.perf_counter() - t0
-        self.block_s += block
+        block = 0.0
+        if step.waited:
+            self._unwaited_run = 0
+            if tr.enabled:
+                tr.begin("block", track="pipeline")
+            t0 = time.perf_counter()
+            jax.block_until_ready(leaves)
+            block = time.perf_counter() - t0
+            self.block_s += block
+            if tr.enabled:
+                tr.end("block", track="pipeline")
+        else:
+            self._unwaited_run += 1
+            self.steps_unwaited += 1
         if tr.enabled:
-            tr.end("block", track="pipeline")
             tr.begin("to_host", track="pipeline")
         step.complete(step.handles)
         step.completed = True
@@ -238,6 +289,7 @@ class StepPipeline:
             "steps": self.steps,
             "drops": self.drops,
             "overlap_hits": self.overlap_hits,
+            "steps_unwaited": self.steps_unwaited,
             "block_s": self.block_s,
             "dispatch_s": self.dispatch_s,
             "starved_s": self.starved_s,
@@ -250,3 +302,11 @@ class StepPipeline:
                            / self.measured_ms_total
                            if self.measured_ms_total > 0.0 else 0.0),
         }
+
+
+def _live_leaves(tree) -> list:
+    """The array leaves of ``tree`` that still hold a buffer: a later step
+    may already have consumed (donated) an output, and the device orders
+    that step's work after this one's, so the rest say when it is done."""
+    return [l for l in jax.tree_util.tree_leaves(tree)
+            if not (hasattr(l, "is_deleted") and l.is_deleted())]

@@ -297,8 +297,11 @@ class VisionEngine:
         # only and never perturb the dispatched math (CI asserts digest
         # equality traced vs untraced)
         self.tracer = tracer if tracer is not None else NULL_TRACER
+        # a step the host reads nothing of goes unwaited; a request's
+        # whole trajectory is at most one segment plan of such steps
         self.pipeline = StepPipeline(self.vc.pipeline_depth,
-                                     tracer=self.tracer)
+                                     tracer=self.tracer,
+                                     max_unwaited=len(self.segments.plan))
         # speculative next-step plan from plan_ahead: (population
         # fingerprint it is valid for, plan). Consumed on fingerprint
         # match; dropped (and replanned) when admissions/retirements made
@@ -849,7 +852,14 @@ class VisionEngine:
         Exactness: all three are pure data movement, so the staged
         buffers are bitwise the batches the synchronous path built
         host-side; the same jitted segment bodies then make the logits
-        independent of pipeline depth."""
+        independent of pipeline depth.
+
+        ``dispatch()`` hands the pipeline only the head and lane outputs,
+        each once: those are what ``complete`` reads on the host. Tile
+        outputs that stay on the device as members' :class:`_Rows` go to
+        the step's ``outputs``, so a step with no head or lane completes
+        without waiting and the host stages the next step while the
+        device runs this one."""
         slots = sorted(self._live)
         now = time.monotonic()
         tr = self.tracer
@@ -987,6 +997,8 @@ class VisionEngine:
 
         # (state, y handle, row, "lane" | "tile") of head and lane outputs
         produced: List[Any] = []
+        handles: List[Any] = []  # head and lane outputs, read on the host
+        kept: List[Any] = []     # tile outputs that stay on the device
 
         def run_tile(run):
             (tile, member_slots, seg, k, soft, prec, batch, n_valid,
@@ -1027,16 +1039,17 @@ class VisionEngine:
                     if soft:
                         st.pkg_mass = _Rows(mass, b)
                 st.seg_idx += 1
+            (handles if kind == "head" else kept).append(y)
             if tr.enabled:
                 tr.end("unstage", track="pipeline")
-            return y
 
         def dispatch():
             # urgent tiles (the plan's leading tiles) dispatch BEFORE
             # lanes: a fused lane is the most expensive single dispatch of
             # the step and must not sit on a deadline-urgent request's
             # critical path
-            handles = [run_tile(run) for run in tile_runs[:n_urgent]]
+            for run in tile_runs[:n_urgent]:
+                run_tile(run)
             for slot, steps, x1, seed in lane_runs:
                 st = self._live[slot]
                 self.precision_dispatches[st.precision] += 1
@@ -1052,7 +1065,8 @@ class VisionEngine:
                 produced.append((st, y, 0, "lane"))
                 st.seg_idx = n_segs
                 handles.append(y)
-            handles += [run_tile(run) for run in tile_runs[n_urgent:]]
+            for run in tile_runs[n_urgent:]:
+                run_tile(run)
             self.planner.commit(plan)
             if q.enabled:
                 q.record(q_dec, q_tight, q_levels,
@@ -1081,13 +1095,15 @@ class VisionEngine:
                           passthrough_tiles=ops.passthrough_tiles,
                           lanes=len(plan.lanes), eager_ops=ops.eager_ops,
                           h2d_puts=ops.h2d_puts, to_host=ops.to_host,
+                          waited=staged.waited,
                           compiled=(self.segments.jit_compile_count()
                                     - compiled0))
 
-        return StagedStep(dispatch=dispatch, complete=complete,
-                          label=f"vit-step-{self.steps}",
-                          modeled_ms=self.planner.cost_model.ms(
-                              plan.stats.modeled_cycles))
+        staged = StagedStep(dispatch=dispatch, complete=complete,
+                            outputs=kept, label=f"vit-step-{self.steps}",
+                            modeled_ms=self.planner.cost_model.ms(
+                                plan.stats.modeled_cycles))
+        return staged
 
     def _record_request(self, st: _Live, path: str, done_s: float) -> None:
         """Log a served request's ``queued`` (enqueue to admission) and

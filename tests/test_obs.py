@@ -491,6 +491,28 @@ def test_engine_spans_nest_in_one_stack_across_tracks(packed_vit, depth):
     assert lanes and all(a["batch"] == 1 and a["steps"] for a in lanes)
 
 
+@pytest.mark.parametrize("depth", [1, 2])
+def test_ragged_mix_bounds_unwaited_runs(packed_vit, depth):
+    """Each ``step`` record says whether its completion waited: a step
+    with a head or a lane waits for its logits, one without goes
+    unwaited, and no run of unwaited steps exceeds the segment plan."""
+    cfg, masked, packed = packed_vit
+    tr = Tracer()
+    eng = VisionEngine(cfg, masked, packed, VisionEngineConfig(
+        max_batch=4, planner="full", pipeline_depth=depth), tracer=tr)
+    eng.serve(_requests(cfg, MIXED + [(9, 0.5, 5), (16, None, 6)]))
+    steps = [s["attrs"] for s in tr.span_log if s["name"] == "step"]
+    waited = [a["waited"] for a in steps]
+    assert all(isinstance(w, bool) for w in waited)
+    assert waited == [a["to_host"] > 0 for a in steps]
+    assert eng.stats()["pipeline_steps_unwaited"] == waited.count(False) > 0
+    run = longest = 0
+    for w in waited:
+        run = 0 if w else run + 1
+        longest = max(longest, run)
+    assert longest <= len(eng.segments.plan)
+
+
 def test_request_records_share_uid_and_order(packed_vit):
     cfg, masked, packed = packed_vit
     tr = Tracer()

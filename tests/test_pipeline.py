@@ -84,9 +84,88 @@ def test_starvation_counts_empty_queue_gaps_only():
     p2.flush()
 
 
+@pytest.mark.parametrize("depth", [1, 2])
+def test_unread_steps_complete_without_blocking_up_to_the_bound(
+        monkeypatch, depth):
+    """A step whose dispatch returns no handles (the host reads none of
+    its outputs) completes without ``block_until_ready``: it counts in
+    ``steps_unwaited`` and never as an overlap hit. After ``max_unwaited``
+    such steps in a row the next one waits on its device ``outputs``; a
+    step with handles always waits on them."""
+    blocked = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: blocked.append(len(x)) or real(x))
+    done = []
+
+    def unread(i):
+        return StagedStep(dispatch=lambda: [], outputs=[jnp.full((2,), i)],
+                          complete=lambda h: done.append(i))
+
+    free = StepPipeline(depth, max_unwaited=100)
+    steps = [unread(i) for i in range(3)]
+    for s in steps:
+        free.submit(s)
+    free.flush()
+    assert done == [0, 1, 2] and blocked == []
+    assert not any(s.waited for s in steps) and all(s.completed
+                                                    for s in steps)
+    st = free.stats()
+    assert st["steps_unwaited"] == 3 and st["overlap_hits"] == 0
+    assert st["block_s"] == 0.0
+
+    bounded = StepPipeline(depth, max_unwaited=2)
+    steps = [unread(i) for i in range(6)] + [_step(6, done)]
+    for s in steps:
+        bounded.submit(s)
+    bounded.flush()
+    assert [s.waited for s in steps] == [False, False, True] * 2 + [True]
+    assert blocked == [1, 1, 1]
+    st = bounded.stats()
+    assert st["steps_unwaited"] == 4 and st["overlap_hits"] <= 3
+
+    # the default bound 0 waits on every step, as the LM engine's do
+    sync = StepPipeline(depth)
+    sync.submit(unread(7))
+    sync.flush()
+    assert blocked == [1, 1, 1, 1] and sync.stats()["steps_unwaited"] == 0
+
+
+def test_bound_waits_on_the_step_that_consumed_the_outputs(monkeypatch):
+    """At the run-ahead bound, a step whose device outputs a later
+    in-flight step already consumed (donated) waits on that step's
+    outputs, which the device runs after it: the bound holds at depth 2
+    too."""
+    blocked = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: blocked.append(x) or real(x))
+    outs = [jnp.full((2,), i) for i in range(3)]
+
+    def consuming(i):
+        def dispatch():
+            if i:
+                outs[i - 1].delete()   # as a donating segment call does
+            return []
+        return StagedStep(dispatch=dispatch, outputs=[outs[i]],
+                          complete=lambda h: None)
+
+    p = StepPipeline(2, max_unwaited=1)
+    steps = [consuming(i) for i in range(3)]
+    for s in steps:
+        p.submit(s)
+    p.flush()
+    assert [s.waited for s in steps] == [False, True, False]
+    assert len(blocked) == 1 and len(blocked[0]) == 1
+    assert blocked[0][0] is outs[2]
+    assert p.stats()["steps_unwaited"] == 2
+
+
 def test_depth_validation():
     with pytest.raises(ValueError, match="depth"):
         StepPipeline(0)
+    with pytest.raises(ValueError, match="max_unwaited"):
+        StepPipeline(1, max_unwaited=-1)
     with pytest.raises(ValueError, match="pipeline_depth"):
         EngineConfig(pipeline_depth=0)
     with pytest.raises(ValueError, match="pipeline_depth"):
@@ -352,28 +431,66 @@ def test_vision_midstep_submission_lands_in_next_plan(packed_vit):
         assert np.array_equal(got[uid], ref[uid])
 
 
-@pytest.mark.parametrize("planner", ["off", "full"])
+@pytest.mark.parametrize("planner", ["off", "full", "merge", "fuse"])
 def test_vision_depth_bitexact(packed_vit, planner):
     """Depth 2 reproduces depth 1 logits bit-for-bit under staggered
-    arrivals and slot churn, and the speculative plan cache actually
-    gets consulted (identical concurrent trajectories never fuse away,
-    so populations persist across steps in every planner mode)."""
+    arrivals, slot churn and soft-pruning requests, and the speculative
+    plan cache actually gets consulted (identical concurrent trajectories
+    never fuse away, so populations persist across steps in every planner
+    mode). Steps the host reads nothing of go unwaited alike at both
+    depths."""
     cfg, masked, packed = packed_vit
     mixes = [(16, None, 0), (16, None, 0), (9, 0.5, 1), (9, 0.5, 2),
-             (16, None, 3)]
+             (16, None, 3), (16, 0.5, 3), (9, None, 4)]
 
     def run(d):
         eng = VisionEngine(cfg, masked, packed,
                            VisionEngineConfig(max_batch=2, planner=planner,
                                               pipeline_depth=d))
-        return eng.serve(_vision_reqs(cfg, mixes)), list(eng.events), eng
+        reqs = _vision_reqs(cfg, mixes)
+        for r in reqs[5:]:
+            r.soft_prune = True
+        return eng.serve(reqs), list(eng.events), eng
 
-    base, base_ev, _ = run(1)
+    base, base_ev, base_eng = run(1)
     got, got_ev, eng = run(2)
     assert got_ev == base_ev
-    assert sorted(got) == sorted(base)
+    assert sorted(got) == sorted(base) == list(range(len(mixes)))
     for uid in base:
         assert np.array_equal(base[uid], got[uid])
     st = eng.stats()
     assert st["pipeline_steps"] == st["steps"]
     assert st["plan_ahead_hits"] + st["plan_ahead_drops"] > 0
+    unwaited = base_eng.stats()["pipeline_steps_unwaited"]
+    assert 0 < unwaited < base_eng.stats()["pipeline_steps"]
+    assert st["pipeline_steps_unwaited"] == unwaited
+
+
+def test_vision_lockstep_cohort_waits_once(packed_vit):
+    """Two lock-step cohorts of ``max_batch`` at depth 1: each step but
+    the head reads nothing on the host and goes unwaited, so a cohort
+    blocks once, and its logits are in ``out`` when the head step's
+    ``tick`` returns."""
+    cfg, masked, packed = packed_vit
+    mb = 4
+    eng = VisionEngine(cfg, masked, packed,
+                       VisionEngineConfig(max_batch=mb, planner="full"))
+    n_segs = len(eng.segments.plan)
+    reqs = _vision_reqs(cfg, [(16, 0.5, 0)] * (2 * mb))
+    eng.enqueue(reqs)
+    out = {}
+    heads = 0
+    while eng._pending or eng.scheduler.has_work():
+        rep = eng.tick(out)
+        if rep.completed:
+            heads += 1
+            assert set(rep.completed) <= set(out)
+            assert eng.pipeline.in_flight == 0
+        else:
+            assert not (set(out) - {r.uid for r in reqs[:heads * mb]})
+    eng.finish()
+    assert heads == 2 and sorted(out) == [r.uid for r in reqs]
+    st = eng.stats()
+    assert st["pipeline_steps"] == 2 * n_segs
+    assert st["pipeline_steps_unwaited"] == 2 * (n_segs - 1)
+    assert st["passthrough_tiles"] > 0
