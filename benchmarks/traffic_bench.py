@@ -8,8 +8,8 @@ ladder of offered loads, expressed as multiples of the engine's measured
 saturation capacity, under three arms:
 
     unbounded  admission off, quality strict — the pre-traffic engine
-               path byte-for-byte (the harness's outputs digest is
-               asserted equal to a direct ``serve()`` call).
+               path (the harness's outputs are asserted equal to a
+               direct ``serve()`` call's, to float32 rounding).
     admission  cost-model admission control (``traffic.admission``),
                quality strict: accept-or-reject against a modeled
                backlog budget.
@@ -52,25 +52,25 @@ from repro.models import model as M
 from repro.models import pruning_glue as PG
 from repro.serving import VisionEngine, VisionEngineConfig
 from repro.traffic import (TraceSpec, TrafficHarness, VisionDriver,
-                           make_trace, outputs_digest, trace_fingerprint)
+                           make_trace, trace_fingerprint)
 
 LOAD_FACTORS = (0.5, 2.0, 4.0)   # offered load as multiples of capacity
 KNEE = 1.0
 
 
-def build_engine(cfg, masked, packed, slots: int, quality: str):
+def build_engine(cfg, masked, slots: int, quality: str):
     return VisionEngine(
-        cfg, masked, packed,
+        cfg, masked,
         VisionEngineConfig(max_batch=slots, planner="full", quality=quality,
                            keep_floor=0.4))
 
 
-def measure_capacity_rps(cfg, masked, packed, slots, sizes, seed):
+def measure_capacity_rps(cfg, masked, slots, sizes, seed):
     """Saturation throughput on the virtual clock: replay a back-to-back
     trace (offered load far above any plausible capacity) and read the
     drain rate. Deterministic — it prices modeled cycles, not wall
     time."""
-    eng = build_engine(cfg, masked, packed, slots, "strict")
+    eng = build_engine(cfg, masked, slots, "strict")
     probe = TraceSpec(n=4 * slots, rate_rps=1e6, process="poisson",
                       sizes=sizes, r_ts=(None,), deadlines_ms=(None,))
     h = TrafficHarness(VisionDriver(eng))
@@ -79,9 +79,9 @@ def measure_capacity_rps(cfg, masked, packed, slots, sizes, seed):
     return rep["throughput_rps"], mean_service_ms, eng
 
 
-def run_arm(cfg, masked, packed, slots, trace, arm, limit_ms):
+def run_arm(cfg, masked, slots, trace, arm, limit_ms):
     quality = "auto" if arm == "degrade" else "strict"
-    eng = build_engine(cfg, masked, packed, slots, quality)
+    eng = build_engine(cfg, masked, slots, quality)
     h = TrafficHarness(VisionDriver(eng),
                        admission_limit_ms=(None if arm == "unbounded"
                                            else limit_ms))
@@ -96,13 +96,11 @@ def bench(arch: str, num: int, slots: int, seed: int, smoke: bool):
     params = M.init_params(cfg, key)
     scores = PG.init_scores(cfg, params, jax.random.fold_in(key, 7))
     masked = PG.apply_pruning(cfg, params, scores)
-    from repro.core import packed_runner as PR
-    packed = PR.pack_model(cfg, params, scores)
 
     side = cfg.image_size // cfg.patch_size
     sizes = tuple(sorted({max(1, side - 1) ** 2, side ** 2}))
     capacity_rps, mean_service_ms, cap_engine = measure_capacity_rps(
-        cfg, masked, packed, slots, sizes, seed)
+        cfg, masked, slots, sizes, seed)
 
     # SLO + budget geometry. Two different units on purpose: the
     # admission budget (``limit_ms``) is in MODELED solo ms — the units
@@ -137,7 +135,7 @@ def bench(arch: str, num: int, slots: int, seed: int, smoke: bool):
         fingerprints[f"x{lf:g}"] = trace_fingerprint(trace)
         point = {}
         for arm in arms:
-            rep, h = run_arm(cfg, masked, packed, slots, trace, arm,
+            rep, h = run_arm(cfg, masked, slots, trace, arm,
                              limit_ms)
             point[arm] = rep
             print(f"  load {lf:g}x {arm:>9}: completed={rep['completed']} "
@@ -149,13 +147,16 @@ def bench(arch: str, num: int, slots: int, seed: int, smoke: bool):
                   f"peakq={rep['peak_queue_depth']}")
             if arm == "unbounded" and lf == factors[0]:
                 # pre-PR equivalence: the harness with admission off must
-                # serve byte-identical outputs to a direct engine.serve()
-                # on the same materialized requests
-                eng = build_engine(cfg, masked, packed, slots, "strict")
+                # serve a direct engine.serve()'s logits on the same
+                # materialized requests (to float32 rounding: merged and
+                # fused programs may sum in another order)
+                eng = build_engine(cfg, masked, slots, "strict")
                 drv = VisionDriver(eng)
                 direct = eng.serve([drv.materialize(t)
                                     for t in trace.requests])
-                same = outputs_digest(direct) == rep["outputs_digest"]
+                same = (sorted(direct) == sorted(h.outputs) and all(
+                    np.allclose(h.outputs[u], lg, rtol=1e-5, atol=1e-5)
+                    for u, lg in direct.items()))
                 point["harness_matches_direct_serve"] = same
                 print(f"  load {lf:g}x harness==direct serve: {same}")
                 ok &= same

@@ -1,7 +1,7 @@
 """Execution planning for ragged ViT serving: naive padding vs balanced
 bucketing vs the cost-model-driven TilePlanner.
 
-The packed ViT's token pruning leaves the in-flight population ragged:
+The pruned ViT's token pruning leaves the in-flight population ragged:
 images enter at different resolutions and shed tokens at every TDM layer at
 their own keep rates. This bench serves identical request streams through
 the ``VisionEngine`` under the batching/planning strategies, over two
@@ -34,7 +34,7 @@ count, deadline dispatches, modeled saving).
 
 Every timed arm runs at ``--pipeline-depth`` (1 = synchronous) and under
 ``--quality`` / ``--keep-floor`` (the QualityController: ``strict`` = off,
-the bit-exact control CI also runs; ``degrade``/``auto`` enable keep-rate
+the control CI also runs; ``degrade``/``auto`` enable keep-rate
 tightening). A quality Pareto block always sweeps the ``degrade`` floor
 over the keep-level grid on a uniform-rate stream and asserts the
 elasticity property: modeled latency strictly decreases as the floor
@@ -81,7 +81,7 @@ def make_requests(cfg, num: int, arrival_spread: int, seed: int,
                size_weights=[0.5, 0.3, 0.2])
 
 
-def calibrate_cost_model(cfg, masked, packed, cost_model, seed: int,
+def calibrate_cost_model(cfg, masked, cost_model, seed: int,
                          reps: int = 3):
     """Fit the cost model's dispatch-overhead constant and cycle->seconds
     scale from measured wall-clock dispatch timings (the satellite hook:
@@ -94,7 +94,7 @@ def calibrate_cost_model(cfg, masked, packed, cost_model, seed: int,
     from repro.core import packed_runner as PR
     from repro.serving import Tile
 
-    probe = PR.PackedVitSegments(cfg, masked, packed)
+    probe = PR.PackedVitSegments(cfg, masked)
     seg = next(s for s in probe.plan if s[0] == "layers")
     si = probe.plan.index(seg)
     rng = np.random.default_rng(seed)
@@ -128,10 +128,9 @@ def outputs_digest(out) -> str:
     return h.hexdigest()
 
 
-def run_mode(cfg, masked, packed, cost_model, reqs_factory, *, slots: int,
+def run_mode(cfg, masked, cost_model, reqs_factory, *, slots: int,
              bmode: str, planner: str, pipeline_depth: int = 1,
              quality: str = "strict", keep_floor: float = 0.4,
-             precision: str = "fp32",
              tracer=None, registry=None, metrics_prefix: str = "vision"):
     """Serve the stream twice (warmup compiles every shape on the identical
     stream — arrival dynamics replay exactly) and time the second pass.
@@ -143,9 +142,8 @@ def run_mode(cfg, masked, packed, cost_model, reqs_factory, *, slots: int,
 
     vc = VisionEngineConfig(max_batch=slots, mode=bmode, token_tile=1,
                             planner=planner, pipeline_depth=pipeline_depth,
-                            quality=quality, keep_floor=keep_floor,
-                            precision=precision)
-    engine = VisionEngine(cfg, masked, packed, vc, cost_model=cost_model,
+                            quality=quality, keep_floor=keep_floor)
+    engine = VisionEngine(cfg, masked, vc, cost_model=cost_model,
                           tracer=tracer)
     engine.serve(reqs_factory())
     warm = engine.stats()
@@ -189,13 +187,10 @@ def run_mode(cfg, masked, packed, cost_model, reqs_factory, *, slots: int,
         "deadline_dispatches": st["plan_deadline_urgent"],
         "modeled_saving_ms": st["plan_modeled_saving_ms"],
         "calibrated": st["plan_calibrated"],
-        # quantized-serving columns (fp32 arms: tier dispatches only)
-        "precision": st["precision"],
-        "dequant_dispatches": st["dequant_dispatches"],
     }
 
 
-def quality_pareto(cfg, masked, packed, cost_model, reqs_factory, *,
+def quality_pareto(cfg, masked, cost_model, reqs_factory, *,
                    slots: int, planner: str, registry=None):
     """The quality-elasticity Pareto sweep: serve the identical stream at
     progressively tighter keep floors (``degrade`` mode pins every
@@ -233,7 +228,7 @@ def quality_pareto(cfg, masked, packed, cost_model, reqs_factory, *,
                                 token_tile=1, planner=planner,
                                 quality=qmode, keep_levels=levels,
                                 keep_floor=floor)
-        eng = VisionEngine(cfg, masked, packed, vc, cost_model=cost_model)
+        eng = VisionEngine(cfg, masked, vc, cost_model=cost_model)
         eng.serve(reqs_factory())  # warmup compiles the arm's shapes
         reqs = reqs_factory()
         t0 = time.time()
@@ -282,87 +277,7 @@ def quality_pareto(cfg, masked, packed, cost_model, reqs_factory, *,
     return rows
 
 
-def precision_compare(cfg, masked, packed, cost_model, reqs_factory, *,
-                      slots: int, planner: str, pipeline_depth: int = 1,
-                      quality: str = "strict", keep_floor: float = 0.4,
-                      registry=None):
-    """The quantized-serving accuracy/latency gate: serve the identical
-    mixed stream through engines at every precision tier and report, per
-    tier, top-1 agreement against the fp32 arm (the accuracy proxy the
-    acceptance criterion gates at >= 0.98), the modeled end-to-end latency
-    of the stream under the tier's dispatched precisions (deterministic —
-    the cost model prices each request's trajectory exactly as the planner
-    did at admission, so int8 < fp32 is a cycle-model fact, immune to CI
-    wall-clock noise), the measured weight-quantization error, and the
-    packed model bytes at the tier. The fp32 arm doubles as the
-    no-regression control: its ``outputs_digest`` must equal the planned
-    mixed arm's whenever that arm also runs fp32 (the pre-quantization
-    serving path, byte-identical stage keys and all)."""
-    import numpy as np
-
-    from repro.serving import VisionEngine, VisionEngineConfig
-
-    rows = []
-    base_top1 = None
-    for tier in ("fp32", "fp16", "int8"):
-        vc = VisionEngineConfig(max_batch=slots, mode="balanced",
-                                token_tile=1, planner=planner,
-                                pipeline_depth=pipeline_depth,
-                                quality=quality, keep_floor=keep_floor,
-                                precision=tier)
-        eng = VisionEngine(cfg, masked, packed, vc, cost_model=cost_model)
-        eng.serve(reqs_factory())  # warmup compiles the tier's shapes
-        reqs = reqs_factory()
-        t0 = time.time()
-        out = eng.serve(reqs)
-        dt = time.time() - t0
-        st = eng.stats()
-        # modeled stream latency under the precisions the planner actually
-        # dispatched (strict requests pin fp32; the rest price at the tier)
-        modeled = 0.0
-        for r in reqs:
-            prec = eng._precision_for(r)
-            traj = eng._traj_from(0, r.n_patches, eng._base_schedule(r),
-                                  r.soft_prune, precision=prec)
-            modeled += cost_model.ms(cost_model.trajectory_cycles(traj))
-        top1 = {u: int(np.argmax(lg)) for u, lg in out.items()}
-        if base_top1 is None:
-            base_top1 = top1
-        agreement = (sum(top1[u] == base_top1[u] for u in top1)
-                     / max(len(top1), 1))
-        rep = eng.quantization_report()
-        if registry is not None:
-            registry.gauge(f"precision.top1_agreement_{tier}").set(agreement)
-            registry.gauge(f"precision.modeled_ms_{tier}").set(modeled)
-            registry.gauge(f"precision.quant_max_abs_error_{tier}").set(
-                rep["quant_max_abs_error"])
-            registry.gauge(f"precision.packed_bytes_{tier}").set(
-                rep["packed_bytes"])
-        rows.append({
-            "precision": tier,
-            "granularity": rep["granularity"],
-            "modeled_ms": modeled,
-            "seconds": dt, "images_s": len(out) / dt,
-            "top1_agreement": agreement,
-            "quant_max_abs_error": rep["quant_max_abs_error"],
-            "packed_bytes": rep["packed_bytes"],
-            "outputs_digest": outputs_digest(out),
-            "served": len(out), "expected": len(reqs),
-            "dispatches": {p: st[f"dispatch_{p}"]
-                           for p in ("fp32", "fp16", "int8")},
-            "dequant_dispatches": st["dequant_dispatches"],
-            "precision_decisions": {
-                p: st[f"plan_precision_{p}"]
-                for p in ("fp32", "fp16", "int8")},
-            "jit_compiles": st["jit_compile_count"],
-            "compile_budget": st["compile_budget"],
-            "recompile_bound_ok":
-                st["jit_compile_count"] <= st["compile_budget"],
-        })
-    return rows
-
-
-def pipeline_compare(cfg, masked, packed, cost_model, reqs_factory, *,
+def pipeline_compare(cfg, masked, cost_model, reqs_factory, *,
                      slots: int, planner: str):
     """Serve the identical mixed stream through the planned arm at
     pipeline depth 1 (synchronous) and 2 (double-buffered): outputs must
@@ -383,8 +298,7 @@ def pipeline_compare(cfg, masked, packed, cost_model, reqs_factory, *,
         vc = VisionEngineConfig(max_batch=slots, mode="balanced",
                                 token_tile=1, planner=planner,
                                 pipeline_depth=depth)
-        engine = VisionEngine(cfg, masked, packed, vc,
-                              cost_model=cost_model)
+        engine = VisionEngine(cfg, masked, vc, cost_model=cost_model)
         engine.serve(reqs_factory())
         warm = engine.stats()
         t0 = time.time()
@@ -417,11 +331,10 @@ def bench(arch: str, num: int, slots: int, arrival_spread: int,
           image_size: int, d_model: int, seed: int, planner: str,
           calibrate: bool, pipeline_depth: int = 1,
           quality: str = "strict", keep_floor: float = 0.4,
-          precision: str = "fp32", tracer=None, registry=None):
+          tracer=None, registry=None):
     import jax
 
     from repro.configs import get_config
-    from repro.core import packed_runner as PR
     from repro.models import model as M
     from repro.models import pruning_glue as PG
     from repro.serving import TileCostModel
@@ -437,12 +350,11 @@ def bench(arch: str, num: int, slots: int, arrival_spread: int,
     params = M.init_params(cfg, key)
     scores = PG.init_scores(cfg, params, jax.random.fold_in(key, 7))
     masked = PG.apply_pruning(cfg, params, scores)
-    packed = PR.pack_model(cfg, params, scores)
 
     cost_model = TileCostModel(cfg)
     fit = None
     if calibrate:
-        fit = calibrate_cost_model(cfg, masked, packed, cost_model, seed)
+        fit = calibrate_cost_model(cfg, masked, cost_model, seed)
 
     mixed = lambda: make_requests(cfg, num, arrival_spread, seed)
     sparse = lambda: make_requests(cfg, num, max(2 * num, arrival_spread),
@@ -461,28 +373,23 @@ def bench(arch: str, num: int, slots: int, arrival_spread: int,
         # it is the one that carries the trace and the metrics snapshot
         planned = mode == "planned"
         results["mixed"][mode] = run_mode(
-            cfg, masked, packed, cost_model, mixed,
+            cfg, masked, cost_model, mixed,
             slots=slots, bmode=bmode, planner=pmode,
             pipeline_depth=pipeline_depth,
-            quality=quality, keep_floor=keep_floor, precision=precision,
+            quality=quality, keep_floor=keep_floor,
             tracer=tracer if planned else None,
             registry=registry if planned else None)
     for mode, pmode in (("balanced", "off"), ("planned", planner)):
         results["sparse"][mode] = run_mode(
-            cfg, masked, packed, cost_model, sparse,
+            cfg, masked, cost_model, sparse,
             slots=slots, bmode="balanced", planner=pmode,
             pipeline_depth=pipeline_depth,
-            quality=quality, keep_floor=keep_floor, precision=precision)
+            quality=quality, keep_floor=keep_floor)
     results["pipeline"] = pipeline_compare(
-        cfg, masked, packed, cost_model, mixed, slots=slots,
-        planner=planner)
+        cfg, masked, cost_model, mixed, slots=slots, planner=planner)
     results["quality_pareto"] = quality_pareto(
-        cfg, masked, packed, cost_model, pareto, slots=slots,
+        cfg, masked, cost_model, pareto, slots=slots,
         planner=planner, registry=registry)
-    results["precision_compare"] = precision_compare(
-        cfg, masked, packed, cost_model, mixed, slots=slots,
-        planner=planner, pipeline_depth=pipeline_depth, quality=quality,
-        keep_floor=keep_floor, registry=registry)
     return results, fit
 
 
@@ -510,18 +417,12 @@ def main():
     ap.add_argument("--quality", default="strict",
                     choices=("strict", "auto", "degrade"),
                     help="QualityController mode for the timed arms "
-                         "(strict = off, bit-exact control; the Pareto "
+                         "(strict = off, the control; the Pareto "
                          "sweep block always runs its own strict + "
                          "degrade-floor arms regardless)")
     ap.add_argument("--keep-floor", type=float, default=0.4,
                     help="controller keep-rate floor for the timed arms "
                          "(no request is tightened below it)")
-    ap.add_argument("--precision", default="fp32",
-                    choices=("fp32", "fp16", "int8"),
-                    help="serving precision tier for the timed arms "
-                         "(fp32 = the bit-exact reference path; the "
-                         "precision_compare block always runs all three "
-                         "tiers regardless)")
     ap.add_argument("--out", default="BENCH_vision.json",
                     help="JSON artifact path")
     ap.add_argument("--trace-out", default="", metavar="PATH",
@@ -546,7 +447,6 @@ def main():
                      args.seed, args.planner, calibrate=not args.smoke,
                      pipeline_depth=args.pipeline_depth,
                      quality=args.quality, keep_floor=args.keep_floor,
-                     precision=args.precision,
                      tracer=tracer, registry=registry)
     if args.trace_out:
         tracer.write_chrome_trace(args.trace_out)
@@ -562,7 +462,7 @@ def main():
            f"{'merges':>6s} {'lanes':>6s} {'save_ms':>8s}")
     print(hdr)
     for scen, modes in res.items():
-        if scen in ("pipeline", "quality_pareto", "precision_compare"):
+        if scen in ("pipeline", "quality_pareto"):
             continue
         for mode, r in modes.items():
             served = f"{r['served']}/{r['expected']}"
@@ -608,42 +508,6 @@ def main():
     print(f"pareto modeled latency strictly decreasing as keep floor "
           f"tightens: {pareto_monotone}")
     ok &= pareto_monotone
-
-    # quantized-serving gate: every tier serves the stream, stays within
-    # its recompile budget, agrees with fp32 on >= 98% of top-1 labels,
-    # and int8's modeled stream latency is strictly below fp32's (the
-    # planner-facing claim: the cheaper tier is really priced cheaper)
-    prec_rows = res["precision_compare"]
-    by_tier = {row["precision"]: row for row in prec_rows}
-    print(f"{'precision':10s} {'modeled_ms':>10s} {'img/s':>8s} "
-          f"{'top1_agree':>10s} {'max|dW|':>9s} {'packed_MB':>9s} "
-          f"{'dequant':>7s} {'jit<=budget':>11s}")
-    for row in prec_rows:
-        budget = f"{row['jit_compiles']}<={row['compile_budget']}"
-        print(f"{row['precision']:10s} {row['modeled_ms']:10.4f} "
-              f"{row['images_s']:8.2f} {row['top1_agreement']:10.2f} "
-              f"{row['quant_max_abs_error']:9.5f} "
-              f"{row['packed_bytes'] / 1e6:9.3f} "
-              f"{row['dequant_dispatches']:7d} {budget:>11s}")
-        ok &= row["served"] == row["expected"]
-        ok &= row["recompile_bound_ok"]
-        if row["top1_agreement"] < 0.98:
-            print(f"FAIL: {row['precision']} top-1 agreement "
-                  f"{row['top1_agreement']:.3f} < 0.98", file=sys.stderr)
-            ok = False
-    if by_tier["int8"]["modeled_ms"] >= by_tier["fp32"]["modeled_ms"]:
-        print(f"FAIL: int8 modeled latency "
-              f"({by_tier['int8']['modeled_ms']:.4f}ms) must be strictly "
-              f"below fp32 ({by_tier['fp32']['modeled_ms']:.4f}ms)",
-              file=sys.stderr)
-        ok = False
-    if args.precision == "fp32" and (
-            by_tier["fp32"]["outputs_digest"]
-            != mixed["planned"]["outputs_digest"]):
-        print("FAIL: fp32 precision_compare arm digest differs from the "
-              "planned mixed arm — the fp32 serving path regressed",
-              file=sys.stderr)
-        ok = False
 
     pipe = res["pipeline"]
     d1, d2 = pipe["depth1"], pipe["depth2"]

@@ -4,11 +4,11 @@
     python3 chip_smoke.py
 
 Builds DeiT-Small at its published widths (12 layers, d_model 384, 6 heads,
-224 px, TDM at layers 2/6/9, r_b 0.5) from a seed, prunes and packs it,
-serves a mixed stream of 8 requests (49/169/196 patches, keep rates
+224 px, TDM at layers 2/6/9, r_b 0.5) from a seed, prunes it, serves a
+mixed stream of 8 requests (49/169/196 patches, keep rates
 0.5/0.7/default) through ``VisionEngine`` with kernels compiled, checks
-that the fp32 encoder segment runs its projections as XLA matmuls and the
-int8 one through the compiled SBMM kernel, and compares every request's
+that the encoder segment runs its projections as XLA matmuls with no
+Pallas kernel, and compares every request's
 logits with the masked-dense reference run at ``highest`` matmul
 precision. A request that misses the tolerance passes only if a TDM top-k
 near-tie made the served path keep other tokens and the logits agree with
@@ -68,15 +68,14 @@ def _served_walk(engine, patches, r_t):
     from repro.core import packed_runner as PR
 
     segs = engine.segments
-    packed = segs.packed_for("fp32")
-    scores_at = jax.jit(lambda params, packed, x, layer: PR._encoder_attn(
-        engine.cfg, params, packed, x, layer, collect_scores=True)[1],
+    scores_at = jax.jit(lambda params, x, layer: PR._encoder_attn(
+        engine.cfg, params, x, layer, collect_scores=True)[1],
         static_argnames="layer")
     x, n, kept = patches, patches.shape[1] + 1, {}
     for seg in segs.plan:
         if seg[0] == "tdm":
             k = PR.tdm_keep_count(n, r_t)
-            s = scores_at(segs.params, packed, x, layer=seg[1])
+            s = scores_at(segs.params, x, layer=seg[1])
             kept[seg[1]] = (np.asarray(jax.lax.top_k(s[0, 1:], k)[1]),
                             np.asarray(s[0]))
             x, n = segs.run(seg, x, k=k), k + 2
@@ -154,10 +153,10 @@ def _explain_by_flips(engine, masked, r, r_t, served):
     return "FAIL", detail
 
 
-def _kernels_in_segment(engine) -> bool:
+def _no_kernel_in_segment(engine) -> bool:
     """Lower and compile one served encoder segment at B=MAX_BATCH, N=197
-    per weight tier and look for the compiled Pallas kernel: the fp32
-    projections are XLA matmuls (none), the int8 ones SBMM (one)."""
+    and look for a compiled Pallas kernel: its projections are XLA
+    matmuls, so there must be none."""
     import jax.numpy as jnp
 
     segs = engine.segments
@@ -165,17 +164,12 @@ def _kernels_in_segment(engine) -> bool:
     n = (engine.cfg.image_size // engine.cfg.patch_size) ** 2 + 1
     x = jnp.zeros((MAX_BATCH, n, engine.cfg.d_model), jnp.float32)
     nv = jnp.full((MAX_BATCH,), n, jnp.int32)
-    ok = True
-    for tier, want in (("fp32", False), ("int8", True)):
-        lowered = segs._layers.lower(segs.params, segs.packed_for(tier), x,
-                                     nv, lo=seg[1], hi=seg[2], prec=tier)
-        in_lowered = "tpu_custom_call" in lowered.as_text()
-        in_compiled = "tpu_custom_call" in lowered.compile().as_text()
-        print(f"{tier} segment {seg} at B={MAX_BATCH} N={n}: "
-              f"tpu_custom_call in lowered={in_lowered} "
-              f"compiled={in_compiled} (expected {want})")
-        ok &= in_lowered == want and in_compiled == want
-    return ok
+    lowered = segs._layers.lower(segs.params, x, nv, lo=seg[1], hi=seg[2])
+    in_lowered = "tpu_custom_call" in lowered.as_text()
+    in_compiled = "tpu_custom_call" in lowered.compile().as_text()
+    print(f"segment {seg} at B={MAX_BATCH} N={n}: tpu_custom_call in "
+          f"lowered={in_lowered} compiled={in_compiled} (expected False)")
+    return not (in_lowered or in_compiled)
 
 
 def smoke(cfg) -> bool:
@@ -220,8 +214,8 @@ def smoke(cfg) -> bool:
         print("FAIL: the warm pass did not reproduce the first pass")
         ok = False
 
-    if not _kernels_in_segment(engine):
-        print("FAIL: a served segment's Pallas kernels are not the tier's")
+    if not _no_kernel_in_segment(engine):
+        print("FAIL: a served segment calls a Pallas kernel")
         ok = False
 
     masked = engine.segments.params

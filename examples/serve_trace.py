@@ -12,8 +12,8 @@ Walkthrough of the ``repro.traffic`` subsystem:
      the ``VisionEngine`` tick by tick; time advances by the cost model's
      price of each dispatched step, so every latency / deadline verdict
      below is deterministic — same on any machine, any pipeline depth.
-     With admission off, the served logits are byte-identical to calling
-     ``engine.serve()`` directly (asserted).
+     With admission off, the served logits match calling
+     ``engine.serve()`` directly, to float32 rounding (asserted).
   3. **Turn on admission control.** The burst overruns the engine;
      the cost-model ``AdmissionController`` bounds the modeled backlog,
      degrading consenting requests to the quality floor before rejecting
@@ -29,15 +29,15 @@ Run: PYTHONPATH=src python examples/serve_trace.py
 import os
 
 import jax
+import numpy as np
 
 from repro.configs import get_config
-from repro.core import packed_runner as PR
 from repro.models import model as M
 from repro.models import pruning_glue as PG
 from repro.serving import (EngineConfig, ServeEngine, VisionEngine,
                            VisionEngineConfig)
 from repro.traffic import (LMDriver, TrafficHarness, VisionDriver,
-                           load_trace, outputs_digest, trace_fingerprint)
+                           load_trace, trace_fingerprint)
 
 TRACE_DIR = os.path.join(os.path.dirname(__file__), "traces")
 
@@ -63,13 +63,12 @@ def main():
     params = M.init_params(cfg, key)
     scores = PG.init_scores(cfg, params, jax.random.fold_in(key, 7))
     masked = PG.apply_pruning(cfg, params, scores)
-    packed = PR.pack_model(cfg, params, scores)
 
     def vision_engine(quality="strict"):
-        return VisionEngine(cfg, masked, packed, VisionEngineConfig(
+        return VisionEngine(cfg, masked, VisionEngineConfig(
             max_batch=2, planner="full", quality=quality))
 
-    # --- 2. unbounded replay == the plain engine, byte for byte -----------
+    # --- 2. unbounded replay == the plain engine ---------------------------
     h = TrafficHarness(VisionDriver(vision_engine()))
     rep = h.run(trace)
     show("vision, unbounded", rep)
@@ -77,9 +76,11 @@ def main():
     eng = vision_engine()
     direct = eng.serve([VisionDriver(eng).materialize(t)
                         for t in trace.requests])
-    assert outputs_digest(direct) == rep["outputs_digest"], \
-        "harness replay must equal direct serve()"
-    print("  unbounded replay is byte-identical to engine.serve()")
+    assert sorted(direct) == sorted(h.outputs)
+    for uid, logits in direct.items():
+        np.testing.assert_allclose(h.outputs[uid], logits, rtol=1e-5,
+                                   atol=1e-5)
+    print("  unbounded replay serves engine.serve()'s logits")
 
     # --- 3. admission control: bounded backlog, degrade before reject -----
     h2 = TrafficHarness(VisionDriver(vision_engine(quality="auto")),

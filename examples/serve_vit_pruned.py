@@ -3,7 +3,8 @@ serving engine (the paper's system-level claim as software).
 
 Pipeline:
   1. simultaneous pruning, hardened: score init -> hard block masks ->
-     masked params (the DBMM path) + SBMM-packed attention weights;
+     masked params (block-masked attention weights, the DBMM path for
+     the MLPs);
   2. a continuous-batching ``VisionEngine``: image requests of mixed
      resolutions and per-request token keep rates admitted through the
      shared ``Scheduler`` (prune-pressure-aware policy), executed as
@@ -12,9 +13,9 @@ Pipeline:
      emits an ``ExecutionPlan`` — dense token-count tiles (bucket-merged
      when the model says padding is cheaper than a dispatch) plus fused
      express lanes for bucket-singleton requests;
-  3. verification: every served logit vector is BIT-EXACT against the
-     single-request offline path (``forward_vit_packed``), regardless of
-     what else was in flight and of what the planner merged or fused;
+  3. verification: every served logit vector matches the single-request
+     offline path (``forward_vit_packed``) to float32 rounding, regardless
+     of what else was in flight and of what the planner merged or fused;
   4. quality elasticity: the same stream re-served through a
      ``QualityController`` (quality='degrade') with per-request
      accuracy/latency preferences — consenting requests are tightened
@@ -22,8 +23,8 @@ Pipeline:
      floor), ``quality='strict'`` requests are pinned to their base
      schedule, and soft-pruning requests fold dropped tokens into a
      weighted package token instead of discarding them. Every degraded
-     logit is still bit-exact against the offline path run at the
-     schedule the controller resolved.
+     logit still matches the offline path run at the schedule the
+     controller resolved.
 
 Run: PYTHONPATH=src python examples/serve_vit_pruned.py
 """
@@ -38,6 +39,10 @@ from repro.models import model as M
 from repro.models import pruning_glue as PG
 from repro.serving import VisionEngine, VisionEngineConfig, VisionRequest
 
+# served logits match the offline walk to float32 rounding: batched,
+# merged and fused programs may sum in another order
+RTOL = ATOL = 1e-5
+
 
 def main():
     cfg = get_config("deit-small").reduced()
@@ -46,9 +51,8 @@ def main():
     scores = PG.init_scores(cfg, params, jax.random.fold_in(key, 7))
 
     # --- 1. harden the pruning (offline, once per model) ------------------
-    masked = PG.apply_pruning(cfg, params, scores)   # DBMM: masked-dense
-    packed = PR.pack_model(cfg, params, scores)      # SBMM: block-packed
-    print(f"packed {len(packed)} attention weights "
+    masked = PG.apply_pruning(cfg, params, scores)   # block-masked weights
+    print(f"masked {4 * cfg.num_layers} attention weights "
           f"(block_size={cfg.pruning.block_size}, r_b={cfg.pruning.r_b}); "
           f"segment plan: {PR.vit_segments(cfg)}")
 
@@ -64,7 +68,7 @@ def main():
         r_t=r_t, arrival_step=i // 2)
         for i, (n, r_t) in enumerate(mixes)]
 
-    engine = VisionEngine(cfg, masked, packed,
+    engine = VisionEngine(cfg, masked,
                           VisionEngineConfig(max_batch=3, planner="full"),
                           policy="prune_pressure_aware")
     out = engine.serve(reqs)
@@ -80,18 +84,19 @@ def main():
     admit_order = [uid for kind, uid in engine.events if kind == "admit"]
     print(f"admission order (prune-pressure-aware): {admit_order}")
 
-    # --- 3. bit-exactness vs the offline single-request path --------------
+    # --- 3. served logits vs the offline single-request path -------------
     for r in reqs:
         c = cfg if r.r_t is None else cfg.replace(
             pruning=dataclasses.replace(cfg.pruning, r_t=r.r_t))
-        ref = PR.forward_vit_packed(c, masked, packed, r.patches[None],
+        ref = PR.forward_vit_packed(c, masked, r.patches[None],
                                     segments=engine.segments)
-        exact = np.array_equal(np.asarray(ref.logits[0]), out[r.uid])
+        close = np.allclose(out[r.uid], np.asarray(ref.logits[0]),
+                            rtol=RTOL, atol=ATOL)
         print(f"  uid {r.uid} ({r.n_patches:2d} patches, "
               f"r_t={r.r_t if r.r_t is not None else cfg.pruning.r_t}): "
               f"top-1 class {int(np.argmax(out[r.uid]))}, "
-              f"bit-exact vs offline: {exact}")
-        assert exact, "batched serving must not change logits"
+              f"matches offline: {close}")
+        assert close, "batched serving must not change logits"
 
     # --- 4. quality-elastic serving ---------------------------------------
     # The controller maps scheduler pressure + per-request preference to a
@@ -106,7 +111,7 @@ def main():
         arrival_step=r.arrival_step) for i, r in enumerate(reqs)]
     qreqs[1].quality = "strict"     # accuracy-critical: opts out
     qreqs[3].soft_prune = True      # latency-tolerant: package token
-    qeng = VisionEngine(cfg, masked, packed,
+    qeng = VisionEngine(cfg, masked,
                         VisionEngineConfig(
                             max_batch=3, planner="full", quality="degrade",
                             keep_levels=(1.0, 0.85, 0.7, 0.55),
@@ -125,62 +130,18 @@ def main():
         # base rate already below the floor level is left untouched)
         base = PR.keep_schedule(cfg, r_t=r.r_t)
         sched = qeng.planner.quality.resolve(base, preference=r.quality)
-        ref = PR.forward_vit_packed(cfg, masked, packed, r.patches[None],
+        ref = PR.forward_vit_packed(cfg, masked, r.patches[None],
                                     schedule=sched, soft=r.soft_prune,
                                     segments=qeng.segments)
-        exact = np.array_equal(np.asarray(ref.logits[0]), qout[r.uid])
+        close = np.allclose(qout[r.uid], np.asarray(ref.logits[0]),
+                            rtol=RTOL, atol=ATOL)
         tag = ("strict" if r.quality == "strict"
                else "soft" if r.soft_prune else "hard")
         print(f"  uid {r.uid} ({tag:6s}): schedule {sched} -> top-1 "
-              f"{int(np.argmax(qout[r.uid]))}, bit-exact vs offline "
-              f"at the resolved schedule: {exact}")
-        assert exact, "the controller changes WHICH schedule runs, " \
+              f"{int(np.argmax(qout[r.uid]))}, matches offline "
+              f"at the resolved schedule: {close}")
+        assert close, "the controller changes WHICH schedule runs, " \
                       "never the math"
-
-    # --- 5. quantized serving (int8 tier) ---------------------------------
-    # The same stream once more through an int8 engine: the planner prices
-    # every request's trajectory at fp32 AND int8 with the accelerator
-    # cycle model and dispatches the dequant-in-kernel SBMM variant when
-    # the tier is strictly cheaper. quality='strict' requests are pinned
-    # to fp32 — their logits are bit-exact with the fp32 engine's — and
-    # every quantized request is still bit-exact against the offline
-    # forward run at the SAME precision (quantization changes the weights
-    # once, offline; serving never changes the math).
-    print("\nquantized re-serve (precision=int8, per-channel scales):")
-    zreqs = [VisionRequest(
-        uid=i, patches=r.patches.copy(), r_t=r.r_t,
-        arrival_step=r.arrival_step) for i, r in enumerate(reqs)]
-    zreqs[1].quality = "strict"     # accuracy-critical: stays fp32
-    zeng = VisionEngine(cfg, masked, packed,
-                        VisionEngineConfig(max_batch=3, planner="full",
-                                           precision="int8"),
-                        policy="prune_pressure_aware")
-    zout = zeng.serve(zreqs)
-    zst = zeng.stats()
-    rep = zeng.quantization_report()
-    print(f"packed model {rep['packed_bytes_fp32']} -> "
-          f"{rep['packed_bytes']} bytes, max|dW|="
-          f"{rep['quant_max_abs_error']:.5f}; dispatches "
-          f"fp32={zst['dispatch_fp32']} int8={zst['dispatch_int8']} "
-          f"(dequant kernels {zst['dequant_dispatches']}), jit compiles "
-          f"{zst['jit_compile_count']} <= {zst['compile_budget']}")
-    agree = 0
-    for r in zreqs:
-        c = cfg if r.r_t is None else cfg.replace(
-            pruning=dataclasses.replace(cfg.pruning, r_t=r.r_t))
-        prec = "fp32" if r.quality == "strict" else "int8"
-        ref = PR.forward_vit_packed(c, masked, packed, r.patches[None],
-                                    segments=zeng.segments, precision=prec)
-        exact = np.array_equal(np.asarray(ref.logits[0]), zout[r.uid])
-        top1 = int(np.argmax(zout[r.uid]))
-        agree += top1 == int(np.argmax(out[r.uid]))
-        tag = "strict/fp32" if r.quality == "strict" else "int8"
-        print(f"  uid {r.uid} ({tag:11s}): top-1 {top1}, bit-exact vs "
-              f"offline at {prec}: {exact}")
-        assert exact, "quantized serving must match the quantized oracle"
-    assert np.array_equal(zout[1], out[1]), \
-        "a strict request on the int8 engine must be bit-exact fp32"
-    print(f"top-1 agreement vs the fp32 serve: {agree}/{len(zreqs)}")
 
 
 if __name__ == "__main__":

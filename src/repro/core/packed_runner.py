@@ -1,18 +1,15 @@
-"""Packed-model execution — the deployment path of the paper's accelerator.
+"""Pruned-ViT execution — the deployment path of the paper's model.
 
-After simultaneous pruning, ``pack_model`` hardens the masks and converts
-every block-pruned attention weight into the block-compressed SBMM format
-(load-balanced column order included). ``forward_vit_packed`` then runs the
-ViT from those weights, validated end-to-end against the masked-dense
-forward (tests/test_packed_runner.py). The fp32 tier multiplies by each
-projection's block-masked dense matrix, rebuilt once from its packed
-blocks: at b = 16 and r_b = 0.5 no 128x128 MXU tile of the weight is
-empty, so a TPU-shaped SBMM can at best match that dense matmul. The
-quantized tiers (``repro.core.quant``) keep the packed format and run the
-SBMM kernels, which stream their int8/fp16 blocks.
-
-MLP column/row-pruned weights stay dense-masked (the paper maps them to
-DBMM — a dense matmul over the shrunken width — which XLA already emits).
+After simultaneous pruning, ``PG.apply_pruning`` hardens the masks into a
+masked parameter tree: every block-pruned attention weight is its
+block-masked dense matrix, every column/row-pruned MLP weight its masked
+dense matrix. The segments below run the ViT from that tree, all
+projections as XLA matmuls: at b = 16 and r_b = 0.5 no 128x128 MXU tile of
+an attention weight is empty, so a TPU-shaped SBMM can at best match the
+dense matmul (the MLPs are the paper's DBMM — a dense matmul over the
+shrunken width — which XLA already emits). ``pack_model`` still converts
+the masked attention weights into the block-compressed format of the
+paper's SBMM kernel library; no serving path runs it.
 
 Per-stage segmentation (serving.vision)
 ---------------------------------------
@@ -45,9 +42,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core import packing
-from repro.core import quant as Q
 from repro.core import token_pruning as TP
-from repro.kernels.sbmm import sbmm
 from repro.models import attention as A
 from repro.models import layers as L
 from repro.models import model as M
@@ -56,8 +51,9 @@ from repro.models import pruning_glue as PG
 
 def pack_model(cfg: ModelConfig, params: Dict, scores: Dict,
                lanes: int = 8) -> Dict[str, packing.PackedWeight]:
-    """Block-compress every masked attention weight. Returns
-    {path: PackedWeight}; paths match pruning_glue.hard_masks keys."""
+    """Block-compress every masked attention weight for the SBMM kernel
+    library. Returns {path: PackedWeight}; paths match
+    pruning_glue.hard_masks keys."""
     masks = PG.hard_masks(cfg, params, scores)
     out = {}
     for path, mask in masks.items():
@@ -78,7 +74,7 @@ Segment = Tuple  # ("embed",) | ("layers", lo, hi) | ("tdm", i) | ("head",)
 
 def vit_segments(cfg: ModelConfig,
                  use_tdm: Optional[bool] = None) -> Tuple[Segment, ...]:
-    """Per-stage segmentation of the packed ViT forward: one segment per
+    """Per-stage segmentation of the pruned ViT forward: one segment per
     maximal run of constant token count, TDM layers as their own segments
     (prune boundaries ARE batching boundaries for the serving engine)."""
     p = cfg.pruning
@@ -172,48 +168,22 @@ def token_trajectory(cfg: ModelConfig, n_patches: int,
 # ===========================================================================
 # Segment bodies (pure functions; jitted by PackedVitSegments)
 # ===========================================================================
-def _proj(params: Dict, packed: Dict, i: int, name: str, inp: jax.Array
-          ) -> jax.Array:
-    """One attention projection by the weight ``packed`` holds for it: a
-    block-compressed weight runs the SBMM kernel, a dense (block-masked)
-    matrix an XLA matmul; an unpruned weight comes from ``params``."""
-    w = packed.get(f"layers/{i}/attn/{name}")
-    if isinstance(w, (packing.PackedWeight, Q.QuantizedPackedWeight)):
-        return sbmm(inp, w)
-    return L.linear(inp, params["layers"][i]["attn"][name] if w is None
-                    else w)
-
-
-def _encoder_attn(cfg: ModelConfig, params: Dict, packed: Dict,
-                  x: jax.Array, i: int, *, collect_scores: bool = False,
-                  n_valid: Optional[jax.Array] = None,
-                  precision: str = "fp32"
+def _encoder_attn(cfg: ModelConfig, params: Dict, x: jax.Array, i: int, *,
+                  collect_scores: bool = False,
+                  n_valid: Optional[jax.Array] = None
                   ) -> Tuple[jax.Array, Optional[jax.Array]]:
-    """Attention sublayer + residual of encoder layer ``i`` (projections
-    by ``_proj``: block-masked dense matmuls at fp32, SBMM for quantized
-    packed weights). ``n_valid`` masks token padding out of the
-    attention and of the TDM scoring; padded rows' scores are exactly 0.
-
-    ``precision`` is the quantized-serving knob: weight precision is
-    carried by the ``packed`` dict itself (int8/fp16 entries dispatch the
-    matching SBMM kernel), while ``"fp16"`` additionally quantizes the
-    attention operands — q/k/v cast to float16 before the online-softmax
-    attention (whose accumulation stays fp32) — with the output and TDM
-    scores returned in fp32 so residuals and top-k run full-precision."""
+    """Attention sublayer + residual of encoder layer ``i``, its
+    projections XLA matmuls by the block-masked weights in ``params``.
+    ``n_valid`` masks token padding out of the attention and of the TDM
+    scoring; padded rows' scores are exactly 0."""
     H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     lp = params["layers"][i]
+    a = lp["attn"]
     h = L.layer_norm(x, lp["ln1_s"], lp["ln1_b"], cfg.norm_eps)
     Bc, Nc, _ = h.shape
-    q = (_proj(params, packed, i, "wq", h)
-         + lp["attn"].get("bq", 0.0)).reshape(Bc, Nc, H, Dh)
-    k = (_proj(params, packed, i, "wk", h)
-         + lp["attn"].get("bk", 0.0)).reshape(Bc, Nc, KV, Dh)
-    v = (_proj(params, packed, i, "wv", h)
-         + lp["attn"].get("bv", 0.0)).reshape(Bc, Nc, KV, Dh)
-    if precision == "fp16":
-        q = q.astype(jnp.float16)
-        k = k.astype(jnp.float16)
-        v = v.astype(jnp.float16)
+    q = (L.linear(h, a["wq"]) + a.get("bq", 0.0)).reshape(Bc, Nc, H, Dh)
+    k = (L.linear(h, a["wk"]) + a.get("bk", 0.0)).reshape(Bc, Nc, KV, Dh)
+    v = (L.linear(h, a["wv"]) + a.get("bv", 0.0)).reshape(Bc, Nc, KV, Dh)
     o = A.flash_attention_jnp(q, k, v, causal=False, kv_len=n_valid)
     o = o.astype(x.dtype)
     scores = None
@@ -221,7 +191,7 @@ def _encoder_attn(cfg: ModelConfig, params: Dict, packed: Dict,
         probs = A.attention_probs_row(q[:, 0], k, kv_len=n_valid)
         scores = probs.mean(axis=1).astype(x.dtype)
     o = o.reshape(Bc, Nc, H * Dh)
-    attn_out = _proj(params, packed, i, "wo", o) + lp["attn"].get("bo", 0.0)
+    attn_out = L.linear(o, a["wo"]) + a.get("bo", 0.0)
     return x + attn_out, scores
 
 
@@ -246,41 +216,35 @@ def vit_embed(cfg: ModelConfig, params: Dict,
     return x + params["pos"][None, : N + 1].astype(adt)
 
 
-def vit_layers(cfg: ModelConfig, params: Dict, packed: Dict, x: jax.Array,
+def vit_layers(cfg: ModelConfig, params: Dict, x: jax.Array,
                lo: int, hi: int,
-               n_valid: Optional[jax.Array] = None,
-               precision: str = "fp32") -> jax.Array:
+               n_valid: Optional[jax.Array] = None) -> jax.Array:
     """Encoder layers [lo, hi) at constant token count."""
     for i in range(lo, hi):
-        x, _ = _encoder_attn(cfg, params, packed, x, i, n_valid=n_valid,
-                             precision=precision)
+        x, _ = _encoder_attn(cfg, params, x, i, n_valid=n_valid)
         x = _encoder_mlp(cfg, params, x, i)
     return x
 
 
-def vit_tdm_layer(cfg: ModelConfig, params: Dict, packed: Dict,
-                  x: jax.Array, layer: int, r_t: Optional[float] = None,
-                  k: Optional[int] = None,
-                  n_valid: Optional[jax.Array] = None,
-                  precision: str = "fp32") -> jax.Array:
+def vit_tdm_layer(cfg: ModelConfig, params: Dict, x: jax.Array, layer: int,
+                  r_t: Optional[float] = None, k: Optional[int] = None,
+                  n_valid: Optional[jax.Array] = None) -> jax.Array:
     """Encoder layer ``layer`` with the TDM between its attention and MLP
     sublayers: [B, N, D] -> [B, k + 2, D] (CLS + k kept + fused). ``k``
     must be passed when rows are token-padded (see ``TP.tdm``); otherwise
     it derives from N and ``r_t`` exactly as the monolithic forward did."""
     if r_t is None:
         r_t = cfg.pruning.r_t
-    x, scores = _encoder_attn(cfg, params, packed, x, layer,
-                              collect_scores=True, n_valid=n_valid,
-                              precision=precision)
+    x, scores = _encoder_attn(cfg, params, x, layer, collect_scores=True,
+                              n_valid=n_valid)
     x, _ = TP.tdm(x, scores, r_t, has_cls=True, k=k)
     return _encoder_mlp(cfg, params, x, layer)
 
 
-def vit_tdm_soft_layer(cfg: ModelConfig, params: Dict, packed: Dict,
-                       x: jax.Array, layer: int, k: int,
+def vit_tdm_soft_layer(cfg: ModelConfig, params: Dict, x: jax.Array,
+                       layer: int, k: int,
                        pkg_mass: Optional[jax.Array] = None,
-                       n_valid: Optional[jax.Array] = None,
-                       precision: str = "fp32"
+                       n_valid: Optional[jax.Array] = None
                        ) -> Tuple[jax.Array, jax.Array]:
     """Soft-pruning variant of :func:`vit_tdm_layer`: the dropped tokens
     fold into a persistent package token (``TP.tdm_soft``). Same output
@@ -289,9 +253,8 @@ def vit_tdm_soft_layer(cfg: ModelConfig, params: Dict, packed: Dict,
     no package row exists yet). With ``pkg_mass``, each row's package sits
     at its own valid-token boundary (body index ``n_valid - 2``) so
     token-padded tiles pin the right row."""
-    x, scores = _encoder_attn(cfg, params, packed, x, layer,
-                              collect_scores=True, n_valid=n_valid,
-                              precision=precision)
+    x, scores = _encoder_attn(cfg, params, x, layer, collect_scores=True,
+                              n_valid=n_valid)
     pkg_pos = None
     if pkg_mass is not None and n_valid is not None:
         pkg_pos = jnp.asarray(n_valid, jnp.int32) - 2
@@ -307,10 +270,9 @@ def vit_head(cfg: ModelConfig, params: Dict, x: jax.Array) -> jax.Array:
     return logits.astype(jnp.float32)
 
 
-def run_fused_steps(cfg: ModelConfig, params: Dict, packed: Dict,
-                    x: jax.Array, steps: Tuple[Tuple, ...],
-                    pkg_mass: Optional[jax.Array] = None,
-                    precision: str = "fp32") -> jax.Array:
+def run_fused_steps(cfg: ModelConfig, params: Dict, x: jax.Array,
+                    steps: Tuple[Tuple, ...],
+                    pkg_mass: Optional[jax.Array] = None) -> jax.Array:
     """Compose consecutive segments into ONE program: ``steps`` is a static
     tuple of ``(segment, k)`` pairs — or ``(segment, k, soft)`` triples for
     soft-pruning TDM steps (``k`` only for TDM segments). This is the
@@ -319,9 +281,7 @@ def run_fused_steps(cfg: ModelConfig, params: Dict, packed: Dict,
     ``n_valid`` is ever needed. All shapes are static given the entry shape
     and the ``k`` sequence. ``pkg_mass`` seeds the package mass for a lane
     entered AFTER a soft request's first TDM already ran tiled (``None``
-    otherwise); the mass threads through in-program across soft steps.
-    ``precision`` applies to the encoder steps only — embed and head run
-    fp32 regardless, matching the tiled path's segment rule."""
+    otherwise); the mass threads through in-program across soft steps."""
     for step in steps:
         seg, k = step[0], step[1]
         soft = bool(step[2]) if len(step) > 2 else False
@@ -329,19 +289,15 @@ def run_fused_steps(cfg: ModelConfig, params: Dict, packed: Dict,
         if kind == "embed":
             x = vit_embed(cfg, params, x)
         elif kind == "layers":
-            x = vit_layers(cfg, params, packed, x, seg[1], seg[2],
-                           precision=precision)
+            x = vit_layers(cfg, params, x, seg[1], seg[2])
         elif kind == "tdm":
             if k is None:
                 raise ValueError("fused tdm steps need an explicit static k")
             if soft:
-                x, pkg_mass = vit_tdm_soft_layer(cfg, params, packed, x,
-                                                 seg[1], k=k,
-                                                 pkg_mass=pkg_mass,
-                                                 precision=precision)
+                x, pkg_mass = vit_tdm_soft_layer(cfg, params, x, seg[1], k=k,
+                                                 pkg_mass=pkg_mass)
             else:
-                x = vit_tdm_layer(cfg, params, packed, x, seg[1], k=k,
-                                  precision=precision)
+                x = vit_tdm_layer(cfg, params, x, seg[1], k=k)
                 pkg_mass = None  # a hard TDM drops/keeps the package like
                 #                  any token; its mass is meaningless after
         elif kind == "head":
@@ -355,14 +311,14 @@ def run_fused_steps(cfg: ModelConfig, params: Dict, packed: Dict,
 # Offline single-batch forward — the segments composed sequentially
 # ===========================================================================
 # Executor memo for forward_vit_packed: id-keyed is safe here because the
-# cached executor holds strong references to its params/packed trees, which
-# pins their ids for exactly as long as the entry lives. Bounded FIFO so
-# sweeps over many packed models don't accumulate jit caches.
+# cached executor holds a strong reference to its params tree, which pins
+# its id for exactly as long as the entry lives. Bounded FIFO so sweeps
+# over many pruned models don't accumulate jit caches.
 _SEGMENT_MEMO: "dict[Tuple, PackedVitSegments]" = {}
 _SEGMENT_MEMO_CAP = 8
 
 
-def _cached_segments(cfg, params, packed, use_tdm) -> "PackedVitSegments":
+def _cached_segments(cfg, params, use_tdm) -> "PackedVitSegments":
     # r_t / tdm_layers only matter through the segment plan (the executor
     # always receives k explicitly), so cfgs differing only in keep rate —
     # the per-request-r_t reference loop — share one executor
@@ -370,49 +326,41 @@ def _cached_segments(cfg, params, packed, use_tdm) -> "PackedVitSegments":
     plan = vit_segments(cfg, use_tdm)
     cfg_norm = cfg.replace(pruning=_dc.replace(cfg.pruning, r_t=1.0,
                                                tdm_layers=()))
-    key = (plan, cfg_norm, id(params), id(packed))
+    key = (plan, cfg_norm, id(params))
     runner = _SEGMENT_MEMO.get(key)
     if runner is None:
-        runner = PackedVitSegments(cfg, params, packed, use_tdm=use_tdm)
+        runner = PackedVitSegments(cfg, params, use_tdm=use_tdm)
         if len(_SEGMENT_MEMO) >= _SEGMENT_MEMO_CAP:
             _SEGMENT_MEMO.pop(next(iter(_SEGMENT_MEMO)))
         _SEGMENT_MEMO[key] = runner
     return runner
-def forward_vit_packed(cfg: ModelConfig, params: Dict,
-                       packed: Dict[str, packing.PackedWeight],
-                       patches: jax.Array,
+
+
+def forward_vit_packed(cfg: ModelConfig, params: Dict, patches: jax.Array,
                        use_tdm: bool | None = None,
                        segments: "Optional[PackedVitSegments]" = None,
                        schedule: Optional[Sequence[float]] = None,
-                       soft: bool = False,
-                       precision: str = "fp32") -> M.Output:
-    """ViT forward with the attention projections taken from ``packed``
-    through the segment executor's weight set at ``precision``: at fp32
-    XLA matmuls by the block-masked dense weights, at int8/fp16 the SBMM
-    kernels (interpret mode on CPU; native Pallas on TPU backends).
+                       soft: bool = False) -> M.Output:
+    """ViT forward through the jitted segment executor, one request at a
+    time: the engine's segments walked unbatched and unpadded.
 
-    ``params`` should be the MASKED tree (``PG.apply_pruning``) so the
-    MLPs run masked-dense (the paper's DBMM path); the packed attention
-    weights carry their masks structurally.
+    ``params`` is the MASKED tree (``PG.apply_pruning``): the attention
+    projections run as XLA matmuls by the block-masked weights and the
+    MLPs masked-dense (the paper's DBMM path).
 
-    This is the single-request oracle the vision serving engine is
-    bit-exact against: it walks the same ``vit_segments`` plan through the
-    same *jitted* segment executor, unbatched and unpadded. (Executing the
-    segments jitted matters for exactness — XLA's fusion choices shift FP
-    reduction order relative to op-by-op eager dispatch, and jitted
-    programs are deterministic given the HLO.) Pass ``segments`` to reuse
-    an already-compiled executor (e.g. an engine's); otherwise one is
-    memoized per (cfg, params, packed, use_tdm) so repeated calls — batch
-    evaluation loops, parity tests — compile once.
+    The serving engine's logits match this walk to rounding (the tests'
+    ``assert_logits_close`` tolerance): a batched, merged or fused program
+    is a different XLA program and may reduce in another order. Pass
+    ``segments`` to reuse an already-compiled executor (e.g. an engine's);
+    otherwise one is memoized per (cfg, params, use_tdm) so repeated calls
+    — batch evaluation loops, parity tests — compile once.
 
     ``schedule`` is a per-TDM-segment keep schedule (``None`` broadcasts
     ``cfg.pruning.r_t``) and ``soft`` selects the package-token soft TDM —
-    together the offline oracle for the serving engine's quality-elastic
-    and soft-pruning paths. ``precision`` runs the encoder segments
-    through the quantized weight set + kernels (``repro.core.quant``) —
-    the single-request oracle for the engine's quantized tiles."""
+    together the offline walk of the serving engine's quality-elastic and
+    soft-pruning paths."""
     runner = segments if segments is not None else _cached_segments(
-        cfg, params, packed, use_tdm)
+        cfg, params, use_tdm)
     if schedule is None:
         schedule = keep_schedule(cfg, use_tdm=use_tdm)
     x = patches
@@ -425,17 +373,16 @@ def forward_vit_packed(cfg: ModelConfig, params: Dict,
             if soft:
                 k = tdm_soft_keep_count(n, r, has_pkg=ordinal > 0)
                 x, pkg_mass = runner.run(seg, x, k=k, soft=True,
-                                         pkg_mass=pkg_mass,
-                                         precision=precision)
+                                         pkg_mass=pkg_mass)
             else:
                 k = tdm_keep_count(n, r)
-                x = runner.run(seg, x, k=k, precision=precision)
+                x = runner.run(seg, x, k=k)
             n = k + 2
             ordinal += 1
         elif seg[0] == "head":
             return M.Output(runner.run(seg, x))
         else:
-            x = runner.run(seg, x, precision=precision)
+            x = runner.run(seg, x)
     raise AssertionError("vit_segments plan must end with ('head',)")
 
 
@@ -460,8 +407,8 @@ def _program(name: str, fn: Callable, **jit_kwargs):
 
 
 class PackedVitSegments:
-    """Owns the jitted per-segment step functions for one
-    (cfg, params, packed) triple, behind a compile ledger.
+    """Owns the jitted per-segment step functions for one (cfg, masked
+    params) pair, behind a compile ledger.
 
     Shape discipline mirrors ``serving.runner.ModelRunner``: each distinct
     (segment, batch tile, token tile, masked?) combination compiles once;
@@ -470,29 +417,12 @@ class PackedVitSegments:
     combinations to its bucket set."""
 
     def __init__(self, cfg: ModelConfig, params: Dict,
-                 packed: Dict[str, packing.PackedWeight],
                  use_tdm: Optional[bool] = None,
-                 donate_activations: bool = False,
-                 quant_granularity: str = "channel"):
+                 donate_activations: bool = False):
         self.cfg = cfg
         self.params = params
-        self.packed = packed
         self.plan = vit_segments(cfg, use_tdm)
         self.donate_activations = donate_activations
-        if quant_granularity not in Q.GRANULARITIES:
-            raise ValueError(
-                f"quant_granularity must be one of {Q.GRANULARITIES}, "
-                f"got {quant_granularity!r}")
-        self.quant_granularity = quant_granularity
-        # The fp32 weight set is every packed projection as its
-        # block-masked dense [K, N] matrix in logical column order, built
-        # on the host and put on the device once. Quantized packed dicts
-        # are derived from ``packed`` lazily on first use — an fp32-only
-        # engine never pays the quantization pass, and precisions share the
-        # one params tree (embed/MLP/head weights are precision-independent:
-        # only the packed attention weights re-quantize).
-        self._packed_by: Dict[str, Dict] = {
-            "fp32": {path: pw.to_dense() for path, pw in packed.items()}}
         # Only the "layers" segment preserves the activation shape
         # [B, n, D] input->output, so only its input tile is donatable
         # (embed/tdm/head change shapes — donating them would just warn
@@ -502,7 +432,7 @@ class PackedVitSegments:
         # on, and forward_vit_packed rebinds x each segment, so both
         # satisfy it; keep the default off for ad-hoc callers
         # that reuse inputs across calls (e.g. timing probes).
-        don = dict(donate_argnums=(2,)) if donate_activations else {}
+        don = dict(donate_argnums=(1,)) if donate_activations else {}
         # each segment program under its own name, so the device trace
         # names its module jit_<name> (jit_vit_layers, jit_vit_lane, ...)
         self._embed = _program(
@@ -510,127 +440,84 @@ class PackedVitSegments:
             lambda params, patches: vit_embed(cfg, params, patches))
         self._layers = _program(
             "vit_layers",
-            lambda params, packed, x, n_valid, lo, hi, prec: vit_layers(
-                cfg, params, packed, x, lo, hi, n_valid=n_valid,
-                precision=prec),
-            static_argnames=("lo", "hi", "prec"), **don)
+            lambda params, x, n_valid, lo, hi: vit_layers(
+                cfg, params, x, lo, hi, n_valid=n_valid),
+            static_argnames=("lo", "hi"), **don)
         self._tdm = _program(
             "vit_tdm",
-            lambda params, packed, x, n_valid, layer, k, prec: vit_tdm_layer(
-                cfg, params, packed, x, layer, k=k, n_valid=n_valid,
-                precision=prec),
-            static_argnames=("layer", "k", "prec"))
+            lambda params, x, n_valid, layer, k: vit_tdm_layer(
+                cfg, params, x, layer, k=k, n_valid=n_valid),
+            static_argnames=("layer", "k"))
         self._tdm_soft = _program(
             "vit_tdm_soft",
-            lambda params, packed, x, n_valid, pkg_mass, layer, k, prec:
-            vit_tdm_soft_layer(cfg, params, packed, x, layer, k=k,
-                               pkg_mass=pkg_mass, n_valid=n_valid,
-                               precision=prec),
-            static_argnames=("layer", "k", "prec"))
+            lambda params, x, n_valid, pkg_mass, layer, k:
+            vit_tdm_soft_layer(cfg, params, x, layer, k=k,
+                               pkg_mass=pkg_mass, n_valid=n_valid),
+            static_argnames=("layer", "k"))
         self._head = _program(
             "vit_head", lambda params, x: vit_head(cfg, params, x))
         self._fused = _program(
             "vit_lane",
-            lambda params, packed, x, pkg_mass, steps, prec: run_fused_steps(
-                cfg, params, packed, x, steps, pkg_mass=pkg_mass,
-                precision=prec),
-            static_argnames=("steps", "prec"))
+            lambda params, x, pkg_mass, steps: run_fused_steps(
+                cfg, params, x, steps, pkg_mass=pkg_mass),
+            static_argnames=("steps",))
         self._compiled: set = set()
         self._fused_trajectories: set = set()
-
-    def packed_for(self, precision: str) -> Dict:
-        """The attention weight set at ``precision``: ``fp32`` is the
-        block-masked dense matrices built at construction; ``fp16``/
-        ``int8`` are quantized lazily on first use from the packed dict
-        via :func:`repro.core.quant.quantize_packed_dict` at this runner's
-        ``quant_granularity``, and memoized so every tile/lane at a given
-        precision shares one set of device buffers."""
-        if precision not in Q.PRECISIONS:
-            raise ValueError(
-                f"precision must be one of {Q.PRECISIONS}, "
-                f"got {precision!r}")
-        pk = self._packed_by.get(precision)
-        if pk is None:
-            pk = Q.quantize_packed_dict(self.packed, precision,
-                                        self.quant_granularity)
-            self._packed_by[precision] = pk
-        return pk
-
-    def _ledger_key(self, base: Tuple, precision: str) -> Tuple:
-        # fp32 keys stay byte-identical to the pre-quantization ledger so
-        # fp32 compile counts / digests are unchanged; other precisions
-        # append a marker (soft-marker ordering preserved: soft, then
-        # precision).
-        return base if precision == "fp32" else base + (precision,)
 
     def run(self, seg: Segment, x: jax.Array,
             n_valid: Optional[np.ndarray] = None,
             k: Optional[int] = None, soft: bool = False,
-            pkg_mass: Optional[jax.Array] = None,
-            precision: str = "fp32"):
+            pkg_mass: Optional[jax.Array] = None):
         """Execute one segment on a dense tile ``x``. ``n_valid`` ([B]) is
         required whenever rows are token-padded; ``k`` is required for
         ``tdm`` segments (uniform across the tile by batcher construction).
         ``soft`` selects the package-token TDM variant: the call takes the
         tile's accumulated package masses (``None`` before the first TDM)
-        and returns ``(y, new_mass)`` instead of ``y``. ``precision``
-        selects the quantized weight set + kernels for the encoder
-        segments; embed and head ignore it (always fp32, so those tiles
-        are shared across precisions and never recompile).
-        """
+        and returns ``(y, new_mass)`` instead of ``y``."""
         kind = seg[0]
         nv = None if n_valid is None else jnp.asarray(n_valid, jnp.int32)
-        base = ((seg, tuple(x.shape), nv is not None, k, "soft") if soft
-                else (seg, tuple(x.shape), nv is not None, k))
+        key = ((seg, tuple(x.shape), nv is not None, k, "soft") if soft
+               else (seg, tuple(x.shape), nv is not None, k))
         if kind == "embed":
-            self._compiled.add(base)
+            self._compiled.add(key)
             return self._embed(self.params, x)
         if kind == "layers":
-            self._compiled.add(self._ledger_key(base, precision))
-            return self._layers(self.params, self.packed_for(precision),
-                                x, nv, lo=seg[1], hi=seg[2], prec=precision)
+            self._compiled.add(key)
+            return self._layers(self.params, x, nv, lo=seg[1], hi=seg[2])
         if kind == "tdm":
             if k is None:
                 raise ValueError("tdm segments need an explicit static k "
                                  "(per-request keep count)")
-            self._compiled.add(self._ledger_key(base, precision))
+            self._compiled.add(key)
             if soft:
-                return self._tdm_soft(self.params,
-                                      self.packed_for(precision), x, nv,
-                                      pkg_mass, layer=seg[1], k=k,
-                                      prec=precision)
-            return self._tdm(self.params, self.packed_for(precision), x, nv,
-                             layer=seg[1], k=k, prec=precision)
+                return self._tdm_soft(self.params, x, nv, pkg_mass,
+                                      layer=seg[1], k=k)
+            return self._tdm(self.params, x, nv, layer=seg[1], k=k)
         if kind == "head":
-            self._compiled.add(base)
+            self._compiled.add(key)
             return self._head(self.params, x)
         raise ValueError(f"unknown segment {seg!r}")
 
     def run_fused(self, steps: Tuple[Tuple, ...], x: jax.Array,
-                  pkg_mass: Optional[jax.Array] = None,
-                  precision: str = "fp32") -> jax.Array:
+                  pkg_mass: Optional[jax.Array] = None) -> jax.Array:
         """Express lane: execute ``steps`` — consecutive ``(segment, k)``
         pairs, or ``(segment, k, soft)`` triples for soft TDM steps — as
         ONE jitted trajectory program (one dispatch for the whole remaining
         forward of a bucket-singleton request). ``pkg_mass`` ([1]) seeds
         the package mass when the lane starts after a soft request's first
-        TDM. Compiles once per distinct (steps, entry shape, precision);
-        the per-trajectory ledger is ``fused_trajectory_count`` and its
-        keys bound the extra jit entries beyond the tile bucket set."""
+        TDM. Compiles once per distinct (steps, entry shape); the
+        per-trajectory ledger is ``fused_trajectory_count`` and its keys
+        bound the extra jit entries beyond the tile bucket set."""
         steps = tuple(
             (tuple(s[0]), None if s[1] is None else int(s[1]))
             + ((True,) if len(s) > 2 and s[2] else ())
             for s in steps)
         if not steps:
             raise ValueError("fused run needs at least one step")
-        traj_key = self._ledger_key((steps, tuple(x.shape)), precision)
-        self._fused_trajectories.add(traj_key)
-        self._compiled.add(self._ledger_key(
-            (("fused",) + steps, tuple(x.shape), False, None), precision))
-        return self._fused(self.params, self.packed_for(precision),
-                           jnp.asarray(x), pkg_mass, steps=steps,
-                           prec=precision)
-
+        self._fused_trajectories.add((steps, tuple(x.shape)))
+        self._compiled.add((("fused",) + steps, tuple(x.shape), False, None))
+        return self._fused(self.params, jnp.asarray(x), pkg_mass,
+                           steps=steps)
     # -- compile observability ---------------------------------------------
     @property
     def compile_count(self) -> int:

@@ -167,7 +167,7 @@ def packed_model_size_bytes(masks_and_weights, block_size: int,
 
     ``masks_and_weights``: iterable of (w_shape, block_mask or None).
     ``dtype_bytes`` is the stored element width (2 = the paper's int16
-    weights; 4/2/1 for the serving fp32/fp16/int8 precisions —
+    weights; 4/2/1 for the kernel library's fp32/fp16/int8 blocks —
     ``repro.core.quant.PRECISION_BYTES``); ``header_bytes`` the per-kept-
     block index width; ``scale_bytes`` (× ``scales_per_block`` per kept
     block, e.g. ``block_size`` for per-output-channel scales) accounts for

@@ -39,9 +39,9 @@ PAPER_U250 = AcceleratorConfig()
 # Precision throughput multipliers for the modeled PE array: halving the
 # operand width doubles the MACs each p_pe² cell packs per cycle (the
 # classic DSP-packing argument on FPGAs; on the MXU, int8/fp16 tiles hit
-# the higher-throughput systolic modes). Deliberately coarse — the planner
-# only needs the ORDERING (int8 < fp16 < fp32) and a stable ratio;
-# ``TileCostModel.calibrate`` owns the absolute scale.
+# the higher-throughput systolic modes). Deliberately coarse — the paper-
+# table model only needs the ORDERING (int8 < fp16 < fp32) and a stable
+# ratio. The serving engine runs float32 only and prices at 1.0.
 PRECISION_SPEEDUP = {"fp32": 1.0, "fp16": 2.0, "int8": 4.0}
 
 
@@ -148,9 +148,9 @@ def vit_segment_cycles(cfg: ModelConfig, seg, n_tokens: int,
     (merge decisions) and to estimate remaining work (deadline slack).
     Segment forms: ``("embed",) | ("layers", lo, hi) | ("tdm", i) |
     ("head",)``. ``precision`` scales the encoder-segment cost by the PE
-    array's narrower-operand throughput (``PRECISION_SPEEDUP``); embed and
-    head always run fp32 in the serving path, so only the weight-bearing
-    ``layers``/``tdm`` segments get the discount."""
+    array's narrower-operand throughput (``PRECISION_SPEEDUP``) for the
+    paper-table model; only the weight-bearing ``layers``/``tdm`` segments
+    get the discount. The serving engine prices every stage at fp32."""
     p = cfg.pruning
     kind = seg[0]
     speed = precision_speedup(precision)

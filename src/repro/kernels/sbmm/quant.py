@@ -105,8 +105,8 @@ def sbmm_quant_ref(x: jnp.ndarray, blocks: jnp.ndarray, header: jnp.ndarray,
     """jnp dequant oracle, accumulation-order-matched to the kernel: per
     block-column, walk kept slots in header order, dequantize the block,
     multiply it (transposed, as the kernel does) with the gathered xᵀ rows
-    in f32, and sum in slot order — bit-identical to an interpret-mode
-    kernel run."""
+    in f32, and sum in slot order — equal to an interpret-mode kernel run
+    up to the rounding of its tiled dots."""
     M, K = x.shape
     C, S, b, _ = blocks.shape
     hdr = np.asarray(header)

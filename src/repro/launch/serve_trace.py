@@ -44,17 +44,15 @@ def build_driver(engine_kind: str, arch: str, slots: int, seed: int,
     harness driver."""
     key = jax.random.PRNGKey(seed)
     if engine_kind == "vision":
-        from repro.core import packed_runner as PR
         from repro.models import pruning_glue as PG
         cfg = get_config(arch or "deit-small").reduced()
         params = M.init_params(cfg, key)
         scores = PG.init_scores(cfg, params, jax.random.fold_in(key, 7))
-        masked = PG.apply_pruning(cfg, params, scores)
-        packed = PR.pack_model(cfg, params, scores)
         vc = VisionEngineConfig(max_batch=slots, planner="full",
                                 pipeline_depth=pipeline_depth,
                                 quality=quality, keep_floor=keep_floor)
-        return VisionDriver(VisionEngine(cfg, masked, packed, vc))
+        return VisionDriver(VisionEngine.from_pruned(cfg, params, scores,
+                                                     vc=vc))
     cfg = get_config(arch or "stablelm-1.6b").reduced()
     params = M.init_params(cfg, key)
     ec = EngineConfig(max_batch=slots, max_len=256,

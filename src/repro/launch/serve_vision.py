@@ -6,7 +6,7 @@
 
 Builds the DeiT config (reduced by default; ``--no-reduced`` serves the
 published widths), runs the paper's simultaneous pruning
-offline (init scores -> hard masks -> SBMM packing), then serves a mixed
+offline (init scores -> hard masks -> masked weights), then serves a mixed
 stream of image resolutions and per-request token keep rates through the
 continuous-batching engine. ``--planner`` selects the execution-planning
 mode (``off`` = PR 4's identity bucketing, ``merge`` = cost-model bucket
@@ -18,7 +18,7 @@ load-balanced bucketing; ``--policy`` selects the admission policy shared
 with the LM path (fifo / shortest_prompt_first / prune_pressure_aware);
 ``--quality`` / ``--keep-floor`` turn on the QualityController (graceful
 quality degradation: keep rates tighten down a quantized grid under
-queue/deadline pressure — ``strict``, the default, is bit-exact off).
+queue/deadline pressure — ``strict``, the default, is off).
 ``--profile-dir DIR`` traces the serve with ``jax.profiler`` and writes the
 engine's spans into that trace as annotations, on the device's clock.
 """
@@ -96,7 +96,7 @@ def serve(arch: str = "deit-small", num_requests: int = 16, slots: int = 4,
           arrival_spread: int = 4, seed: int = 0,
           planner: str = "full", deadline_ms: float = 0.0,
           pipeline_depth: int = 1, quality: str = "strict",
-          keep_floor: float = 0.4, precision: str = "fp32",
+          keep_floor: float = 0.4,
           trace_out: str = "", metrics_out: str = "",
           reduced: bool = True, profile_dir: str = ""):
     cfg = get_config(arch)
@@ -112,8 +112,7 @@ def serve(arch: str = "deit-small", num_requests: int = 16, slots: int = 4,
     vc = VisionEngineConfig(max_batch=slots, mode=mode,
                             token_tile=token_tile, planner=planner,
                             pipeline_depth=pipeline_depth,
-                            quality=quality, keep_floor=keep_floor,
-                            precision=precision)
+                            quality=quality, keep_floor=keep_floor)
     tracer = None
     if trace_out or profile_dir:
         tracer = Tracer(annotate=bool(profile_dir))
@@ -137,8 +136,7 @@ def serve(arch: str = "deit-small", num_requests: int = 16, slots: int = 4,
     return {"outputs": out, "seconds": dt,
             "images_per_s": len(out) / dt,
             "events": list(engine.events),
-            "stats": engine.stats(),
-            "quantization": engine.quantization_report()}
+            "stats": engine.stats()}
 
 
 def main():
@@ -157,7 +155,7 @@ def main():
                          "every request; 0 = no deadlines (deadline-aware "
                          "tiling is active in every non-off planner mode)")
     ap.add_argument("--token-tile", type=int, default=1,
-                    help="token bucket quantization (1 = exact, bit-exact)")
+                    help="token bucket rounding (1 = exact token counts)")
     ap.add_argument("--policy", default="fifo",
                     help="admission policy: fifo | shortest_prompt_first "
                          "| prune_pressure_aware")
@@ -172,24 +170,17 @@ def main():
     ap.add_argument("--pipeline-depth", type=int, default=1,
                     help="StepPipeline depth: 1 = synchronous stepping "
                          "(the reference path), 2 = stage/plan step N+1 "
-                         "while the device executes step N (bit-exact)")
+                         "while the device executes step N")
     ap.add_argument("--quality", default="strict",
                     choices=("strict", "auto", "degrade"),
-                    help="QualityController mode: strict = off (bit-exact "
-                         "with the fixed-keep-rate path), auto = tighten "
+                    help="QualityController mode: strict = off (every "
+                         "request at its own keep rate), auto = tighten "
                          "keep rates with queue/deadline pressure, "
                          "degrade = shed-load floor for every consenting "
                          "request")
     ap.add_argument("--keep-floor", type=float, default=0.4,
                     help="controller keep-rate floor: no request is ever "
                          "tightened below this, whatever the load")
-    ap.add_argument("--precision", default="fp32",
-                    choices=("fp32", "fp16", "int8"),
-                    help="serving precision tier: fp32 = bit-exact "
-                         "reference; fp16/int8 let the planner price each "
-                         "request's trajectory at the tier and dispatch "
-                         "the dequant-in-kernel variants when strictly "
-                         "cheaper (quality=strict requests stay fp32)")
     ap.add_argument("--trace-out", default="", metavar="PATH",
                     help="write a Chrome trace_event JSON (Perfetto-"
                          "loadable) of the run's plan/stage/dispatch/"
@@ -210,8 +201,8 @@ def main():
                 args.token_tile, args.policy, args.image_size,
                 args.arrival_spread, args.seed, args.planner,
                 args.deadline_ms, args.pipeline_depth, args.quality,
-                args.keep_floor, precision=args.precision,
-                trace_out=args.trace_out, metrics_out=args.metrics_out,
+                args.keep_floor, trace_out=args.trace_out,
+                metrics_out=args.metrics_out,
                 reduced=args.reduced, profile_dir=args.profile_dir)
     if args.json:
         print(json.dumps({
@@ -230,16 +221,6 @@ def main():
               f"jit_compiles={st['jit_compile_count']} <= "
               f"buckets+trajectories={st['compile_budget']}")
         print(plan_stats_line(st))
-        q = out["quantization"]
-        print(f"precision={st['precision']} "
-              f"(granularity={q['granularity']}) "
-              f"quant_error={q['quant_max_abs_error']:.5f} "
-              f"packed_bytes={q['packed_bytes_fp32']} -> "
-              f"{q['packed_bytes']} "
-              f"dispatches=" + "/".join(
-                  f"{p}:{st[f'dispatch_{p}']}"
-                  for p in ("fp32", "fp16", "int8")) +
-              f" dequant={st['dequant_dispatches']}")
         if st["quality_mode"] != "strict":
             print(f"quality={st['quality_mode']} "
                   f"floor={st['quality_keep_floor']} tightened="

@@ -10,7 +10,7 @@ bucket merging, express-lane fusion, deadline-aware tiling; owns the
 ``QualityController`` that resolves per-request keep schedules under
 load) + ``core.packed_runner.PackedVitSegments`` compose into
 ``VisionEngine`` —
-continuous-batching inference for the packed, simultaneously-pruned ViT.
+continuous-batching inference for the simultaneously-pruned ViT.
 
 Both engines drive their step loops through the ``StepPipeline``
 (``repro.serving.pipeline``): steps are staged (plan + input buffers),

@@ -1,4 +1,4 @@
-"""VisionEngine — continuous-batching inference for the packed, pruned ViT.
+"""VisionEngine — continuous-batching inference for the pruned ViT.
 
 The paper's headline system claim is an accelerator that *serves* the
 simultaneously-pruned ViT: multi-level parallelism plus load balancing for
@@ -21,15 +21,14 @@ pruning. This engine is the software twin of that serving layer:
   bucket ∪ trajectory set. ``VisionEngineConfig.planner="off"`` (default)
   is the identity plan — exactly PR 4's ``RaggedBatcher.plan`` behavior.
 
-Bit-exactness: in the default ``balanced`` mode with ``token_tile=1``,
-buckets hold requests at *identical* token counts, the batch dimension is
-padded with don't-care rows (rows are computationally independent), and the
-jitted segment bodies are the same pure functions the offline
-single-request path composes — so every request's logits are bit-exact
-against ``forward_vit_packed`` regardless of batch composition
-(tests/test_vision_engine.py). ``token_tile > 1`` and ``naive`` mode
-token-pad rows inside masked kernels: same math, FP reduction order may
-differ.
+Correctness: every request's logits match the float32 masked-dense
+reference (``M.forward_vit`` on the masked params at the request's keep
+rate) and the single-request walk ``forward_vit_packed`` to rounding,
+whatever the batch composition, planner mode or pipeline depth
+(``assert_logits_close`` in tests/conftest.py states the tolerance).
+Batching, token padding, merging and fusion run the same pure segment
+bodies in other XLA programs, which may reduce in another order; padded
+rows and tokens are masked and never reach a member's logits.
 
 Requests may carry per-request keep rates (``r_t``) and arbitrary patch
 counts (images of different resolutions) — both are sources of raggedness;
@@ -48,9 +47,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core import packed_runner as PR
-from repro.core import quant as Q
-from repro.kernels.backend import default_interpret
-from repro.kernels.sbmm.sbmm import FP16_UNSUPPORTED
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import NULL_TRACER, Tracer
 from repro.serving.planner import (PLANNER_MODES, PlanItem, TileCostModel,
@@ -80,7 +76,7 @@ class VisionRequest:
     quality: Optional[str] = None    # accuracy/latency preference for the
     # QualityController: "strict" pins the base schedule even under load,
     # "degrade" invites maximum tightening, None follows the engine mode.
-    # Ignored (bit-exactly) while the engine controller is off.
+    # Ignored while the engine controller is off.
     soft_prune: bool = False         # serve with the soft-pruning TDM:
     # dropped tokens fold into a persistent package token instead of being
     # re-fused per layer (keeps accuracy honest at aggressive keep rates)
@@ -105,37 +101,28 @@ class VisionRequest:
 @dataclasses.dataclass
 class VisionEngineConfig:
     max_batch: int = 8        # in-flight image slots
-    token_tile: int = 1       # bucket quantization (1 = exact, bit-exact)
+    token_tile: int = 1       # bucket rounding (1 = exact token counts)
     mode: str = "balanced"    # 'balanced' buckets | 'naive' pad-to-max
     planner: str = "off"      # TilePlanner mode: off|merge|fuse|full
     use_tdm: Optional[bool] = None   # None = cfg.pruning.token_pruning_enabled
     pipeline_depth: int = 1   # StepPipeline depth: 1 = synchronous,
     # 2 = double-buffered (host plans/stages step N+1 while the device
-    # executes step N; results bit-exact at any depth)
+    # executes step N; same programs and plans at any depth)
     quality: str = "strict"   # QualityController mode: strict = off
-    # (bit-exact with the pre-controller path), auto = tighten keep rates
+    # (every request at its own keep rate), auto = tighten keep rates
     # with queue/deadline pressure, degrade = shed-load floor
     keep_levels: Tuple[float, ...] = (1.0, 0.85, 0.7, 0.55, 0.4)
     # quantized keep-rate grid the controller resolves onto (bounds the
     # distinct TDM k values, hence recompiles)
     keep_floor: float = 0.4   # no request is ever tightened below this
-    precision: str = "fp32"   # serving precision tier: "fp32" is the
-    # bit-exact reference path; "fp16"/"int8" make that tier available to
-    # the planner, which prices each request's trajectory at both fp32 and
-    # the tier and picks the cheaper (fp32 ties win). Requests with
-    # quality="strict" are always pinned to fp32. Encoder segments only —
-    # embed and head run fp32 at every tier.
-    quant_granularity: str = "channel"  # int8 scale granularity:
-    # "block" = one scale per kept block, "channel" = per output channel
+    precision: str = "fp32"   # the engine serves float32 only; the
+    # field stays for configuration files that still state it
 
     def __post_init__(self):
-        if self.precision not in Q.PRECISIONS:
-            raise ValueError(f"VisionEngineConfig.precision must be one of "
-                             f"{Q.PRECISIONS}, got {self.precision!r}")
-        if self.quant_granularity not in Q.GRANULARITIES:
-            raise ValueError(f"VisionEngineConfig.quant_granularity must be "
-                             f"one of {Q.GRANULARITIES}, "
-                             f"got {self.quant_granularity!r}")
+        if self.precision != "fp32":
+            raise ValueError(f"VisionEngineConfig.precision must be 'fp32' "
+                             f"(the int8/fp16 serving tiers were removed), "
+                             f"got {self.precision!r}")
         if self.max_batch <= 0:
             raise ValueError(f"VisionEngineConfig.max_batch must be a "
                              f"positive slot count, got {self.max_batch}")
@@ -244,18 +231,15 @@ class _Live:
     # x/n_tokens
     admit_t: float = 0.0  # monotonic admission time (deadline slack base)
     admit_s: float = 0.0  # perf_counter admission time (tracing only)
-    precision: str = "fp32"  # execution precision chosen at admission
-    # (planner-priced; "strict" quality pins fp32) — static per request so
-    # its stage keys, and therefore its tiles, stay precision-uniform
 
 
 class VisionEngine:
-    """Single-host reference engine for packed-ViT serving. Exposes the
+    """Single-host reference engine for pruned-ViT serving. Exposes the
     layers as ``.scheduler`` / ``.planner`` (owning ``.batcher``) /
     ``.segments`` for tests, policies, and telemetry (mirroring
     ``ServeEngine``'s three layers)."""
 
-    def __init__(self, cfg: ModelConfig, params: Dict, packed: Dict,
+    def __init__(self, cfg: ModelConfig, params: Dict,
                  vc: Optional[VisionEngineConfig] = None,
                  policy: "str | Callable" = "fifo",
                  cost_model: Optional[TileCostModel] = None,
@@ -265,17 +249,13 @@ class VisionEngine:
                              f"got {cfg.family!r}")
         self.cfg = cfg
         self.vc = vc if vc is not None else VisionEngineConfig()
-        if self.vc.precision == "fp16" and not default_interpret():
-            raise ValueError(FP16_UNSUPPORTED)
         # the engine never re-reads a dispatched tile's batch: it is a
         # fresh padded stack, or an output passed through whole, whose
         # every row belongs to the tile's members (each then moves on to
         # the new output). So layers tiles can donate their input buffers
         # to the output allocation
         self.segments = PR.PackedVitSegments(
-            cfg, params, packed, use_tdm=self.vc.use_tdm,
-            donate_activations=True,
-            quant_granularity=self.vc.quant_granularity)
+            cfg, params, use_tdm=self.vc.use_tdm, donate_activations=True)
         self.scheduler = Scheduler(self.vc.max_batch, policy=policy)
         self.batcher = RaggedBatcher(token_tile=self.vc.token_tile,
                                      mode=self.vc.mode,
@@ -320,12 +300,6 @@ class VisionEngine:
         # device work outside the segment programs, summed over completed
         # steps (always counted; a traced step also logs its own)
         self.device_ops = DeviceOps()
-        # quantization observability: tiles+lanes dispatched per precision,
-        # and how many of those went through the dequant-in-kernel int8
-        # SBMM path (counted at the dispatch phase, like planner.commit)
-        self.precision_dispatches: Dict[str, int] = {
-            p: 0 for p in Q.PRECISIONS}
-        self.dequant_dispatches = 0
         self._n_patches_max = (cfg.image_size // cfg.patch_size) ** 2
         self._use_tdm = (cfg.pruning.token_pruning_enabled
                          if self.vc.use_tdm is None else self.vc.use_tdm)
@@ -346,13 +320,11 @@ class VisionEngine:
                     vc: Optional[VisionEngineConfig] = None,
                     policy: "str | Callable" = "fifo",
                     tracer: Optional[Tracer] = None) -> "VisionEngine":
-        """Harden the pruning and build the engine: masks the dense params
-        (the DBMM path) and SBMM-packs the attention weights."""
+        """Harden the pruning and build the engine on the masked params:
+        block-masked attention weights, masked MLPs (the DBMM path)."""
         from repro.models import pruning_glue as PG
         masked = PG.apply_pruning(cfg, params, scores)
-        packed = PR.pack_model(cfg, params, scores)
-        return cls(cfg, masked, packed, vc=vc, policy=policy,
-                   tracer=tracer)
+        return cls(cfg, masked, vc=vc, policy=policy, tracer=tracer)
 
     # -- events / compat ---------------------------------------------------
     @property
@@ -408,8 +380,8 @@ class VisionEngine:
                     # consumes slack, so urgency RISES while queued.
                     cm = self.planner.cost_model
                     r.solo_ms = cm.ms(cm.trajectory_cycles(
-                        self._traj_from(0, r.n_patches, sched, r.soft_prune,
-                                        precision=self._precision_for(r))))
+                        self._traj_from(0, r.n_patches, sched,
+                                        r.soft_prune)))
                     r.prune_load *= min(1.0, r.deadline_ms
                                         / max(r.solo_ms, 1e-9))
             self._pending.append((base + r.arrival_step, r))
@@ -484,8 +456,7 @@ class VisionEngine:
                  else self._base_schedule(r))
         cm = self.planner.cost_model
         return cm.ms(cm.trajectory_cycles(
-            self._traj_from(0, r.n_patches, sched, r.soft_prune,
-                            precision=self._precision_for(r))))
+            self._traj_from(0, r.n_patches, sched, r.soft_prune)))
 
     def modeled_backlog_ms(self) -> float:
         """Modeled time to drain the engine's current commitment: the
@@ -496,8 +467,7 @@ class VisionEngine:
         ms = sum(self.modeled_request_ms(r) for r in self.scheduler.waiting)
         for st in self._live.values():
             ms += cm.ms(cm.trajectory_cycles(self._traj_from(
-                st.seg_idx, st.n_tokens, st.schedule, st.soft,
-                precision=st.precision)))
+                st.seg_idx, st.n_tokens, st.schedule, st.soft)))
         return ms
 
     def stats(self) -> Dict[str, Any]:
@@ -515,13 +485,6 @@ class VisionEngine:
             "compile_budget": buckets + trajectories,
             "plan_ahead_hits": self.plan_ahead_hits,
             "plan_ahead_drops": self.plan_ahead_drops,
-            # quantized-serving counters: the engine tier, tile+lane
-            # dispatches per execution precision, and how many dispatches
-            # ran the dequant-in-kernel int8 SBMM
-            "precision": self.vc.precision,
-            **{f"dispatch_{p}": n
-               for p, n in self.precision_dispatches.items()},
-            "dequant_dispatches": self.dequant_dispatches,
             # eager_ops / h2d_puts / to_host / passthrough_tiles of every
             # completed step
             **dataclasses.asdict(self.device_ops),
@@ -541,9 +504,7 @@ class VisionEngine:
         device idle, backlog), plus the signals the flat dicts cannot
         carry — the modeled-vs-measured plan cost error (calibration
         drift) and the quality controller's tighten count per keep
-        level. The quantization counters (``dispatch_<precision>``,
-        ``dequant_dispatches``, the planner's ``plan_precision_*``
-        decisions) ride the absorb like every other numeric stat."""
+        level."""
         registry.absorb(prefix, self.stats())
         p = self.pipeline.stats()
         registry.gauge(f"{prefix}.plan_cost_error").set(p["cost_error"])
@@ -551,26 +512,6 @@ class VisionEngine:
             registry.gauge(
                 f"{prefix}.quality_tightened_level_{lvl:g}").set(n)
         return registry
-
-    def quantization_report(self) -> Dict[str, Any]:
-        """Weight-quantization accounting at the engine's precision tier:
-        the max-abs weight delta vs the fp32 packed dict (the launcher's
-        quantization-error stat) and the packed model size at both tiers
-        (``PackedWeight.nbytes`` semantics — surviving blocks + headers +
-        scales, at actual dtype widths). fp32 engines report a zero error
-        without ever building a quantized dict."""
-        fp32_bytes = Q.packed_dict_nbytes(self.segments.packed)
-        rep = {"precision": self.vc.precision,
-               "granularity": self.vc.quant_granularity,
-               "packed_bytes_fp32": fp32_bytes,
-               "packed_bytes": fp32_bytes,
-               "quant_max_abs_error": 0.0}
-        if self.vc.precision != "fp32":
-            qd = self.segments.packed_for(self.vc.precision)
-            rep["packed_bytes"] = Q.packed_dict_nbytes(qd)
-            rep["quant_max_abs_error"] = Q.max_abs_error(
-                self.segments.packed, qd)
-        return rep
 
     # -- engine internals --------------------------------------------------
     def _validate(self, r: VisionRequest) -> None:
@@ -630,26 +571,7 @@ class VisionEngine:
                 schedule=self._base_schedule(req),
                 soft=req.soft_prune,
                 admit_t=time.monotonic(),
-                admit_s=time.perf_counter() if self.tracer.enabled else 0.0,
-                precision=self._precision_for(req, record=True))
-
-    def _precision_for(self, r: VisionRequest, record: bool = False) -> str:
-        """Execution precision for ``r`` — the planner's third knob. fp32
-        engines short-circuit (no planner call, no counters: the fp32 path
-        stays byte-identical to the pre-quantization engine), and
-        quality="strict" requests pin fp32 on any engine. Otherwise the
-        planner prices the request's full trajectory at fp32 AND at the
-        engine tier and takes the strict argmin (fp32 listed first, so
-        ties keep full precision). ``record=True`` only at admission —
-        pricing probes (modeled_request_ms / backlog) must not inflate the
-        decision counters."""
-        if self.vc.precision == "fp32" or r.quality == "strict":
-            return "fp32"
-        sched = self._base_schedule(r)
-        cands = [(p, self._traj_from(0, r.n_patches, sched, r.soft_prune,
-                                     precision=p))
-                 for p in ("fp32", self.vc.precision)]
-        return self.planner.choose_precision(cands, record=record)
+                admit_s=time.perf_counter() if self.tracer.enabled else 0.0)
 
     def _base_schedule(self, r: VisionRequest) -> Tuple[float, ...]:
         """The request's own per-TDM keep schedule BEFORE any controller
@@ -673,8 +595,7 @@ class VisionEngine:
                 1.0, max(left, 0.0) / max(req.solo_ms, 1e-9))
 
     def _traj_from(self, seg_idx: int, n_tokens: int,
-                   schedule: Sequence[float], soft: bool = False,
-                   precision: str = "fp32"):
+                   schedule: Sequence[float], soft: bool = False):
         """Remaining (stage key, entry token count) trajectory from segment
         ``seg_idx`` at ``n_tokens`` real tokens under ``schedule`` (full
         per-TDM keep schedule; entries before this point are history —
@@ -685,16 +606,8 @@ class VisionEngine:
         TDM stages append a ``"soft"`` marker (different kernel, and the
         package row makes padded-batch membership semantics different), so
         soft and hard requests never share a TDM tile while non-TDM
-        segments still batch together. Non-fp32 ``precision`` appends the
-        precision string to the weight-bearing (layers/tdm) stage keys
-        (after the soft marker) — different weights and kernels, so
-        precisions never share an encoder tile and the cost model prices
-        them at their own throughput; embed/head keys stay unmarked (those
-        tiles run fp32 at every tier and batch across precisions), and
-        fp32 keys are byte-identical to the pre-quantization ones. Offsets
-        align with engine steps, which is what the planner's fusion and
-        deadline logic rely on."""
-        mark = () if precision == "fp32" else (precision,)
+        segments still batch together. Offsets align with engine steps,
+        which is what the planner's fusion and deadline logic rely on."""
         entries = []
         n = n_tokens
         ti = self._tdm_before[seg_idx]
@@ -704,14 +617,12 @@ class VisionEngine:
                 r = schedule[ti]
                 if soft:
                     k = PR.tdm_soft_keep_count(n, r, has_pkg=ti > 0)
-                    entries.append(((si, seg, k, "soft") + mark, n))
+                    entries.append(((si, seg, k, "soft"), n))
                 else:
                     k = PR.tdm_keep_count(n, r)
-                    entries.append(((si, seg, k) + mark, n))
+                    entries.append(((si, seg, k), n))
                 n = k + 2
                 ti += 1
-            elif seg[0] == "layers":
-                entries.append(((si, seg, None) + mark, n))
             else:
                 entries.append(((si, seg, None), n))
                 if seg[0] == "embed":
@@ -735,8 +646,7 @@ class VisionEngine:
 
             def rem(sched, _st=st, _cm=cm):
                 return _cm.ms(_cm.trajectory_cycles(self._traj_from(
-                    _st.seg_idx, _st.n_tokens, sched, _st.soft,
-                    precision=_st.precision)))
+                    _st.seg_idx, _st.n_tokens, sched, _st.soft)))
 
         # backlog pressure comes from the Scheduler's first-class counter —
         # the same number its stats() block (and the traffic harness) report
@@ -747,8 +657,7 @@ class VisionEngine:
 
     def _plan_item(self, st: _Live, now: float,
                    schedule: Sequence[float]) -> PlanItem:
-        traj = self._traj_from(st.seg_idx, st.n_tokens, schedule, st.soft,
-                               precision=st.precision)
+        traj = self._traj_from(st.seg_idx, st.n_tokens, schedule, st.soft)
         left = None
         if st.req.deadline_ms is not None:
             left = st.req.deadline_ms - (now - st.admit_t) * 1e3
@@ -757,17 +666,10 @@ class VisionEngine:
                         deadline_left_ms=left)
 
     @staticmethod
-    def _parse_stage(stage) -> Tuple[Tuple, Optional[int], bool, str]:
-        """Decompose an engine stage key into ``(segment, k, soft,
-        precision)`` — the inverse of ``_traj_from``'s key construction:
-        ``(si, segment, k[, "soft"][, precision])`` with both trailing
-        markers optional ("soft" is not a precision string, so membership
-        in ``Q.PRECISIONS`` disambiguates)."""
-        seg, k = stage[1], stage[2]
-        rest = stage[3:]
-        soft = "soft" in rest
-        precision = next((m for m in rest if m in Q.PRECISIONS), "fp32")
-        return seg, k, soft, precision
+    def _parse_stage(stage) -> Tuple[Tuple, Optional[int], bool]:
+        """Decompose an engine stage key ``(si, segment, k[, "soft"])``
+        into ``(segment, k, soft)`` — the inverse of ``_traj_from``."""
+        return stage[1], stage[2], stage[3:] == ("soft",)
 
     def _token_cap(self, st: _Live) -> Optional[int]:
         """Hard bound on the padded token tile: the embed stage indexes the
@@ -791,7 +693,7 @@ class VisionEngine:
         population matches the prediction (the common case between
         admissions at depth >= 2): plans are deterministic values of the
         item population, so the speculative plan IS the plan a fresh
-        ``plan_ahead(items, 1)[0]`` would build — bit-identical behavior,
+        ``plan_ahead(items, 1)[0]`` would build — the same plan, its
         planning cost hidden behind the previous step's device work."""
         key = self._items_fingerprint(items)
         cached, self._plan_cache = self._plan_cache, None
@@ -914,9 +816,8 @@ class VisionEngine:
             member_slots = [slots[i] for i in tile.members]
             states = [self._live[s] for s in member_slots]
             # the tile's stage key is the source of truth for what runs:
-            # (si, segment, k[, "soft"][, precision]) — states[0] only
-            # supplies data
-            seg, k, soft, prec = self._parse_stage(tile.stage)
+            # (si, segment, k[, "soft"]) — states[0] only supplies data
+            seg, k, soft = self._parse_stage(tile.stage)
             batch = self._passthrough(tile, states)
             passed = batch is not None
             if passed:
@@ -968,7 +869,7 @@ class VisionEngine:
                 ops.eager_ops += (len(states) + 1
                                   + _stack_programs(tile.b_tile))
                 ops.h2d_puts += 1
-            tile_runs.append((tile, member_slots, seg, k, soft, prec, batch,
+            tile_runs.append((tile, member_slots, seg, k, soft, batch,
                               n_valid, pkg_mass, passed))
 
         lane_runs = []
@@ -977,7 +878,7 @@ class VisionEngine:
             st = self._live[slot]
             steps = []
             for stage, _ in lane.trajectory:
-                seg, k, soft, _prec = self._parse_stage(stage)
+                seg, k, soft = self._parse_stage(stage)
                 steps.append((seg, k, True) if soft else (seg, k))
             steps = tuple(steps)
             seed = None
@@ -1001,13 +902,10 @@ class VisionEngine:
         kept: List[Any] = []     # tile outputs that stay on the device
 
         def run_tile(run):
-            (tile, member_slots, seg, k, soft, prec, batch, n_valid,
+            (tile, member_slots, seg, k, soft, batch, n_valid,
              pkg_mass, passed) = run
             if not passed:
                 self._stacked.add(tile.bucket_key)
-            self.precision_dispatches[prec] += 1
-            if prec == "int8":
-                self.dequant_dispatches += 1
             if tr.enabled:
                 tr.begin("tile", track="pipeline", seg=seg,
                          batch=tile.b_tile, n=tile.n_tile, k=k)
@@ -1015,11 +913,9 @@ class VisionEngine:
             if soft:
                 y, mass = self.segments.run(seg, batch, n_valid=n_valid,
                                             k=k, soft=True,
-                                            pkg_mass=pkg_mass,
-                                            precision=prec)
+                                            pkg_mass=pkg_mass)
             else:
-                y = self.segments.run(seg, batch, n_valid=n_valid, k=k,
-                                      precision=prec)
+                y = self.segments.run(seg, batch, n_valid=n_valid, k=k)
             if tr.enabled:
                 tr.end("tile", track="pipeline")
                 tr.begin("unstage", track="pipeline")
@@ -1052,14 +948,10 @@ class VisionEngine:
                 run_tile(run)
             for slot, steps, x1, seed in lane_runs:
                 st = self._live[slot]
-                self.precision_dispatches[st.precision] += 1
-                if st.precision == "int8":
-                    self.dequant_dispatches += 1
                 if tr.enabled:
                     tr.begin("lane", track="pipeline", steps=steps, batch=1,
                              n=x1.shape[1])
-                y = self.segments.run_fused(steps, x1, pkg_mass=seed,
-                                            precision=st.precision)
+                y = self.segments.run_fused(steps, x1, pkg_mass=seed)
                 if tr.enabled:
                     tr.end("lane", track="pipeline")
                 produced.append((st, y, 0, "lane"))

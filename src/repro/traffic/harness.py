@@ -236,9 +236,9 @@ class TrafficHarness:
     ``admission_limit_ms`` (optional) builds + installs the driver's
     :class:`AdmissionController` on the engine's Scheduler before the
     replay; pass ``controller`` instead to install a pre-built one. With
-    neither, admission is unbounded — the pre-PR behavior, byte-for-byte
-    (``outputs_digest`` equality with a direct ``serve()`` call on the
-    same requests is tested).
+    neither, admission is unbounded: every request is served, with the
+    logits a direct ``serve()`` call on the same requests gives (tested
+    within the logits tolerance of tests/conftest.py).
 
     ``tracer`` (optional, ``repro.obs``) makes the replay emit
     virtual-clock spans: per dispatched step, nested

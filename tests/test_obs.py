@@ -274,13 +274,12 @@ def packed_vit(rng_key):
     params = M.init_params(cfg, rng_key)
     scores = PG.init_scores(cfg, params, jax.random.fold_in(rng_key, 7))
     masked = PG.apply_pruning(cfg, params, scores)
-    packed = PR.pack_model(cfg, params, scores)
-    return cfg, masked, packed
+    return cfg, masked
 
 
 def _vision_engine(packed_vit, depth=1):
-    cfg, masked, packed = packed_vit
-    return VisionEngine(cfg, masked, packed, VisionEngineConfig(
+    cfg, masked = packed_vit
+    return VisionEngine(cfg, masked, VisionEngineConfig(
         max_batch=2, planner="full", pipeline_depth=depth))
 
 
@@ -340,9 +339,9 @@ def test_harness_tracing_is_digest_neutral(packed_vit):
 def test_engine_wallclock_spans_balanced(packed_vit):
     # engine + pipeline tracks (plan/stage/dispatch/complete) on the real
     # clock: the export must validate with no dangling spans after serve
-    cfg, masked, packed = packed_vit
+    cfg, masked = packed_vit
     tr = Tracer()
-    eng = VisionEngine(cfg, masked, packed,
+    eng = VisionEngine(cfg, masked,
                        VisionEngineConfig(max_batch=2, planner="full"),
                        tracer=tr)
     from repro.launch.serve_vision import make_requests
@@ -474,9 +473,9 @@ MIXED = [(16, 0.5, 0), (16, 0.5, 0), (9, 0.7, 0), (16, None, 1),
 
 @pytest.mark.parametrize("depth", [1, 2])
 def test_engine_spans_nest_in_one_stack_across_tracks(packed_vit, depth):
-    cfg, masked, packed = packed_vit
+    cfg, masked = packed_vit
     tr = _OneStackTracer()
-    eng = VisionEngine(cfg, masked, packed, VisionEngineConfig(
+    eng = VisionEngine(cfg, masked, VisionEngineConfig(
         max_batch=4, planner="full", pipeline_depth=depth), tracer=tr)
     out = eng.serve(_requests(cfg, MIXED))
     assert len(out) == len(MIXED) and tr.stack == [] and not tr.open_spans()
@@ -496,9 +495,9 @@ def test_ragged_mix_bounds_unwaited_runs(packed_vit, depth):
     """Each ``step`` record says whether its completion waited: a step
     with a head or a lane waits for its logits, one without goes
     unwaited, and no run of unwaited steps exceeds the segment plan."""
-    cfg, masked, packed = packed_vit
+    cfg, masked = packed_vit
     tr = Tracer()
-    eng = VisionEngine(cfg, masked, packed, VisionEngineConfig(
+    eng = VisionEngine(cfg, masked, VisionEngineConfig(
         max_batch=4, planner="full", pipeline_depth=depth), tracer=tr)
     eng.serve(_requests(cfg, MIXED + [(9, 0.5, 5), (16, None, 6)]))
     steps = [s["attrs"] for s in tr.span_log if s["name"] == "step"]
@@ -514,9 +513,9 @@ def test_ragged_mix_bounds_unwaited_runs(packed_vit, depth):
 
 
 def test_request_records_share_uid_and_order(packed_vit):
-    cfg, masked, packed = packed_vit
+    cfg, masked = packed_vit
     tr = Tracer()
-    eng = VisionEngine(cfg, masked, packed, VisionEngineConfig(
+    eng = VisionEngine(cfg, masked, VisionEngineConfig(
         max_batch=2, planner="full"), tracer=tr)
     reqs = _requests(cfg, MIXED)
     eng.serve(reqs)
@@ -543,9 +542,9 @@ def test_eager_ops_match_a_hand_count(packed_vit):
     """Three requests of one size and keep rate at max_batch 4 (planner
     off): every step runs one 3-member tile padded with a zero row, so no
     tile passes the previous output through."""
-    cfg, masked, packed = packed_vit
+    cfg, masked = packed_vit
     tr = Tracer()
-    eng = VisionEngine(cfg, masked, packed, VisionEngineConfig(
+    eng = VisionEngine(cfg, masked, VisionEngineConfig(
         max_batch=4), tracer=tr)
     before = eng.stats()
     eng.serve(_requests(cfg, [(16, 0.5, 0)] * 3))
@@ -581,8 +580,8 @@ def test_eager_ops_match_a_hand_count(packed_vit):
 
 
 def test_engine_counts_device_ops_with_tracing_off(packed_vit):
-    cfg, masked, packed = packed_vit
-    eng = VisionEngine(cfg, masked, packed, VisionEngineConfig(max_batch=4))
+    cfg, masked = packed_vit
+    eng = VisionEngine(cfg, masked, VisionEngineConfig(max_batch=4))
     eng.serve(_requests(cfg, [(16, 0.5, 0)] * 3))
     n = len(eng.segments.plan)
     st = eng.stats()
@@ -591,10 +590,10 @@ def test_engine_counts_device_ops_with_tracing_off(packed_vit):
 
 
 def test_tracing_keeps_logits_bitexact(packed_vit):
-    cfg, masked, packed = packed_vit
+    cfg, masked = packed_vit
     outs = []
     for tracer in (None, _OneStackTracer()):
-        eng = VisionEngine(cfg, masked, packed, VisionEngineConfig(
+        eng = VisionEngine(cfg, masked, VisionEngineConfig(
             max_batch=4, planner="full"), tracer=tracer)
         outs.append(eng.serve(_requests(cfg, MIXED)))
     assert sorted(outs[0]) == sorted(outs[1])
@@ -604,26 +603,23 @@ def test_tracing_keeps_logits_bitexact(packed_vit):
 
 def test_segment_programs_lower_to_named_modules(packed_vit):
     import jax.numpy as jnp
-    cfg, masked, packed = packed_vit
-    seg = PR.PackedVitSegments(cfg, masked, packed)
+    cfg, masked = packed_vit
+    seg = PR.PackedVitSegments(cfg, masked)
     P = cfg.patch_size ** 2 * 3
     D = cfg.d_model
     x = jnp.zeros((1, 5, D), jnp.float32)
-    pk = seg.packed_for("fp32")
     tdm = next(s for s in seg.plan if s[0] == "tdm")
     embed_steps = tuple((s, PR.tdm_keep_count(5, 0.5) if s[0] == "tdm"
                          else None) for s in seg.plan)
     lowered = {
         "vit_embed": seg._embed.lower(masked, jnp.zeros((1, 4, P))),
-        "vit_layers": seg._layers.lower(masked, pk, x, None, lo=0, hi=1,
-                                        prec="fp32"),
-        "vit_tdm": seg._tdm.lower(masked, pk, x, None, layer=tdm[1], k=2,
-                                  prec="fp32"),
-        "vit_tdm_soft": seg._tdm_soft.lower(masked, pk, x, None, None,
-                                            layer=tdm[1], k=2, prec="fp32"),
+        "vit_layers": seg._layers.lower(masked, x, None, lo=0, hi=1),
+        "vit_tdm": seg._tdm.lower(masked, x, None, layer=tdm[1], k=2),
+        "vit_tdm_soft": seg._tdm_soft.lower(masked, x, None, None,
+                                            layer=tdm[1], k=2),
         "vit_head": seg._head.lower(masked, x),
-        "vit_lane": seg._fused.lower(masked, pk, jnp.zeros((1, 4, P)), None,
-                                     steps=embed_steps, prec="fp32"),
+        "vit_lane": seg._fused.lower(masked, jnp.zeros((1, 4, P)), None,
+                                     steps=embed_steps),
     }
     for name, low in lowered.items():
         assert low.as_text().startswith(f"module @jit_{name} "), name

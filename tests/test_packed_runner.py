@@ -1,7 +1,8 @@
-"""End-to-end packed-model execution: the packed ViT must match the
-masked-dense oracle — the accelerator-vs-software parity check. Its fp32
-projections are XLA matmuls by the block-masked weights; the quantized
-tiers run them through the SBMM kernels."""
+"""End-to-end pruned-model execution: the segment walk must match the
+masked-dense oracle — the accelerator-vs-software parity check. Its
+projections are XLA matmuls by the block-masked weights of the masked
+parameter tree; ``pack_model`` packs the same weights for the SBMM kernel
+library."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -23,12 +24,16 @@ def test_packed_vit_matches_masked_dense(rng_key):
     n = (cfg.image_size // cfg.patch_size) ** 2
     patches = jax.random.normal(rng_key, (2, n, cfg.patch_size ** 2 * 3))
 
-    # the deployment path runs MLPs masked-dense (DBMM analog) and, at
-    # fp32, the attention projections by the block-masked dense weights
-    # rebuilt from the packed blocks; pass the masked tree for the MLPs
+    # the deployment path runs MLPs masked-dense (DBMM analog) and the
+    # attention projections by the block-masked dense weights, which are
+    # exactly what the packed blocks hold
     masked = PG.apply_pruning(cfg, params, scores)
-    out_packed = PR.forward_vit_packed(cfg, masked, packed, patches,
-                                       use_tdm=False)
+    for path, pw in packed.items():
+        _, i, _, name = path.split("/")
+        np.testing.assert_array_equal(
+            np.asarray(masked["layers"][int(i)]["attn"][name]),
+            pw.to_dense())
+    out_packed = PR.forward_vit_packed(cfg, masked, patches, use_tdm=False)
     out_oracle = PR.masked_dense_reference(cfg, params, scores, patches,
                                            use_tdm=False)
     np.testing.assert_allclose(np.asarray(out_packed.logits),
@@ -36,19 +41,16 @@ def test_packed_vit_matches_masked_dense(rng_key):
                                atol=2e-3, rtol=2e-3)
 
 
-@pytest.mark.parametrize("tier", ["fp32", "int8"])
+@pytest.mark.parametrize("tier", ["fp32"])
 def test_sbmm_kernel_only_in_quantized_tier_programs(rng_key, tier):
     """The served programs that hold attention projections (tiles of
-    encoder layers, TDM layers, and express lanes) call no Pallas kernel at
-    fp32, where the projections are XLA matmuls by the block-masked
-    weights, and the SBMM kernel at int8."""
+    encoder layers, TDM layers, and express lanes) call no Pallas kernel:
+    the projections are XLA matmuls by the block-masked weights."""
     cfg = DEIT_SMALL.reduced()
     params = M.init_params(cfg, rng_key)
     scores = PG.init_scores(cfg, params, jax.random.fold_in(rng_key, 7))
     masked = PG.apply_pruning(cfg, params, scores)
-    seg = PR.PackedVitSegments(cfg, masked, PR.pack_model(cfg, params,
-                                                          scores))
-    pk = seg.packed_for(tier)
+    seg = PR.PackedVitSegments(cfg, masked)
     n = (cfg.image_size // cfg.patch_size) ** 2
     x = jnp.zeros((2, n + 1, cfg.d_model), jnp.float32)
     layers = next(s for s in seg.plan if s[0] == "layers")
@@ -56,20 +58,15 @@ def test_sbmm_kernel_only_in_quantized_tier_programs(rng_key, tier):
     lane = tuple((s, PR.tdm_keep_count(n + 1, 0.5) if s[0] == "tdm"
                   else None) for s in seg.plan)
     traced = {
-        "vit_layers": seg._layers.trace(masked, pk, x, None, lo=layers[1],
-                                        hi=layers[2], prec=tier),
-        "vit_tdm": seg._tdm.trace(masked, pk, x, None, layer=tdm[1], k=2,
-                                  prec=tier),
+        "vit_layers": seg._layers.trace(masked, x, None, lo=layers[1],
+                                        hi=layers[2]),
+        "vit_tdm": seg._tdm.trace(masked, x, None, layer=tdm[1], k=2),
         "vit_lane": seg._fused.trace(
-            masked, pk, jnp.zeros((1, n, cfg.patch_size ** 2 * 3)), None,
-            steps=lane, prec=tier),
+            masked, jnp.zeros((1, n, cfg.patch_size ** 2 * 3)), None,
+            steps=lane),
     }
     for name, tr in traced.items():
-        n_kernels = str(tr.jaxpr).count("pallas_call")
-        if tier == "fp32":
-            assert n_kernels == 0, name
-        else:
-            assert n_kernels >= 1, name
+        assert str(tr.jaxpr).count("pallas_call") == 0, name
 
 
 def test_packed_vit_with_tdm_runs(rng_key):
@@ -78,10 +75,10 @@ def test_packed_vit_with_tdm_runs(rng_key):
     cfg = DEIT_SMALL.reduced()
     params = M.init_params(cfg, rng_key)
     scores = PG.init_scores(cfg, params, jax.random.fold_in(rng_key, 7))
-    packed = PR.pack_model(cfg, params, scores)
+    masked = PG.apply_pruning(cfg, params, scores)
     n = (cfg.image_size // cfg.patch_size) ** 2
     patches = jax.random.normal(rng_key, (2, n, cfg.patch_size ** 2 * 3))
-    out = PR.forward_vit_packed(cfg, params, packed, patches, use_tdm=True)
+    out = PR.forward_vit_packed(cfg, masked, patches, use_tdm=True)
     assert out.logits.shape == (2, cfg.num_classes)
     assert bool(jnp.isfinite(out.logits).all())
 
@@ -153,10 +150,9 @@ def test_forward_soft_matches_fused_soft_lane(rng_key):
     params = M.init_params(cfg, rng_key)
     scores = PG.init_scores(cfg, params, jax.random.fold_in(rng_key, 7))
     masked = PG.apply_pruning(cfg, params, scores)
-    packed = PR.pack_model(cfg, params, scores)
     n = 16
     patches = jax.random.normal(rng_key, (1, n, cfg.patch_size ** 2 * 3))
-    seq = PR.forward_vit_packed(cfg, masked, packed, patches, use_tdm=True,
+    seq = PR.forward_vit_packed(cfg, masked, patches, use_tdm=True,
                                 soft=True)
     sched = PR.keep_schedule(cfg, use_tdm=True)
     traj = PR.token_trajectory(cfg, n, use_tdm=True, soft=True)
@@ -171,6 +167,6 @@ def test_forward_soft_matches_fused_soft_lane(rng_key):
         else:
             steps.append((seg, None))
         cur = after
-    fused = PR.run_fused_steps(cfg, masked, packed, patches, tuple(steps))
+    fused = PR.run_fused_steps(cfg, masked, patches, tuple(steps))
     np.testing.assert_allclose(np.asarray(fused),
                                np.asarray(seq.logits), atol=1e-5)

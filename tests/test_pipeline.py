@@ -328,8 +328,7 @@ def packed_vit():
     params = M.init_params(cfg, key)
     scores = PG.init_scores(cfg, params, jax.random.fold_in(key, 7))
     masked = PG.apply_pruning(cfg, params, scores)
-    packed = PR.pack_model(cfg, params, scores)
-    return cfg, masked, packed
+    return cfg, masked
 
 
 def _vision_reqs(cfg, mixes, seed=0):
@@ -346,8 +345,8 @@ def test_vision_stage_then_drop_leaves_no_trace(packed_vit):
     dropped step must leave the planner/batcher ledgers, the step
     counter, and every in-flight request exactly as they were — and the
     restaged steps must still serve the requests to completion."""
-    cfg, masked, packed = packed_vit
-    eng = VisionEngine(cfg, masked, packed,
+    cfg, masked = packed_vit
+    eng = VisionEngine(cfg, masked,
                        VisionEngineConfig(max_batch=3, planner="full",
                                           pipeline_depth=2))
     reqs = _vision_reqs(cfg, [(16, None, 0), (9, 0.5, 0)])
@@ -380,7 +379,7 @@ def test_vision_stage_then_drop_leaves_no_trace(packed_vit):
         c = cfg if r.r_t is None else cfg.replace(
             pruning=dataclasses.replace(cfg.pruning, r_t=r.r_t))
         ref = np.asarray(PR.forward_vit_packed(
-            c, masked, packed, r.patches[None]).logits[0])
+            c, masked, r.patches[None]).logits[0])
         assert np.array_equal(out[r.uid], ref)
 
 
@@ -389,11 +388,11 @@ def test_vision_midstep_submission_lands_in_next_plan(packed_vit):
     drop + replan: it joins the REBUILT plan for this step (never mutates
     the staged one), and the whole serve is bit-exact — logits and event
     stream — against submitting it at the step boundary."""
-    cfg, masked, packed = packed_vit
+    cfg, masked = packed_vit
     mixes = [(16, None, 0), (9, 0.5, 0), (4, 0.7, 0)]
 
     def run(hook):
-        eng = VisionEngine(cfg, masked, packed,
+        eng = VisionEngine(cfg, masked,
                            VisionEngineConfig(max_batch=4, planner="full",
                                               pipeline_depth=2))
         reqs = _vision_reqs(cfg, mixes)
@@ -439,12 +438,12 @@ def test_vision_depth_bitexact(packed_vit, planner):
     never fuse away, so populations persist across steps in every planner
     mode). Steps the host reads nothing of go unwaited alike at both
     depths."""
-    cfg, masked, packed = packed_vit
+    cfg, masked = packed_vit
     mixes = [(16, None, 0), (16, None, 0), (9, 0.5, 1), (9, 0.5, 2),
              (16, None, 3), (16, 0.5, 3), (9, None, 4)]
 
     def run(d):
-        eng = VisionEngine(cfg, masked, packed,
+        eng = VisionEngine(cfg, masked,
                            VisionEngineConfig(max_batch=2, planner=planner,
                                               pipeline_depth=d))
         reqs = _vision_reqs(cfg, mixes)
@@ -471,9 +470,9 @@ def test_vision_lockstep_cohort_waits_once(packed_vit):
     the head reads nothing on the host and goes unwaited, so a cohort
     blocks once, and its logits are in ``out`` when the head step's
     ``tick`` returns."""
-    cfg, masked, packed = packed_vit
+    cfg, masked = packed_vit
     mb = 4
-    eng = VisionEngine(cfg, masked, packed,
+    eng = VisionEngine(cfg, masked,
                        VisionEngineConfig(max_batch=mb, planner="full"))
     n_segs = len(eng.segments.plan)
     reqs = _vision_reqs(cfg, [(16, 0.5, 0)] * (2 * mb))

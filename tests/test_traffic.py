@@ -20,8 +20,8 @@ import jax
 import numpy as np
 import pytest
 
+from conftest import assert_logits_close
 from repro.configs import DEIT_SMALL
-from repro.core import packed_runner as PR
 from repro.models import model as M
 from repro.models import pruning_glue as PG
 from repro.serving import (EngineConfig, ServeEngine, Scheduler,
@@ -39,13 +39,12 @@ def packed_vit(rng_key):
     params = M.init_params(cfg, rng_key)
     scores = PG.init_scores(cfg, params, jax.random.fold_in(rng_key, 7))
     masked = PG.apply_pruning(cfg, params, scores)
-    packed = PR.pack_model(cfg, params, scores)
-    return cfg, masked, packed
+    return cfg, masked
 
 
 def _vision_engine(packed_vit, depth=1, quality="strict", slots=2):
-    cfg, masked, packed = packed_vit
-    return VisionEngine(cfg, masked, packed, VisionEngineConfig(
+    cfg, masked = packed_vit
+    return VisionEngine(cfg, masked, VisionEngineConfig(
         max_batch=slots, planner="full", pipeline_depth=depth,
         quality=quality))
 
@@ -249,7 +248,9 @@ def test_vision_harness_equals_direct_serve(packed_vit):
     eng = _vision_engine(packed_vit)
     drv = VisionDriver(eng)
     direct = eng.serve([drv.materialize(t) for t in trace.requests])
-    assert outputs_digest(direct) == rep["outputs_digest"]
+    assert sorted(h.outputs) == sorted(direct)
+    for uid in direct:
+        assert_logits_close(h.outputs[uid], direct[uid])
 
 
 def test_vision_deadline_accounting(packed_vit):
